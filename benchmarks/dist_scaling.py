@@ -1,10 +1,11 @@
-"""bench_dist: chunk throughput of the GSPMD-sharded CCA engine vs device
-count.  The XLA fake-device count is locked at first jax init, so each
-device count runs in its own subprocess (worker mode below); the driver
-collects ``results/bench_dist.json`` so the perf trajectory captures
-scaling (ISSUE 2 satellite).
+"""bench_dist: chunk throughput of the GSPMD-sharded CCA engine vs fake
+CPU host-device count.  The XLA fake-device count is locked at first jax
+init, so each device count runs in its own CPU-only subprocess (worker
+mode below); the parent collects ``results/bench_dist.json``.  These are
+CPU numbers.  On a TPU host it refuses to run: one process holds the
+chip, and the sharded path on real chips is ``chip_smoke.py --chips 4``.
 
-  PYTHONPATH=src python -m benchmarks.run --scale ci --only dist
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m benchmarks.run --scale ci --only dist
 """
 from __future__ import annotations
 
@@ -28,11 +29,11 @@ def worker(scale: str, devices: int) -> dict:
     """Runs inside a subprocess whose XLA_FLAGS pin the device count."""
     import jax
     import numpy as np
+    from jax.sharding import AxisType
     from repro.core.apps import BFS
     from repro.core.config import EngineConfig
     from repro.core.engine import StreamingEngine, run_chunk_body
     from repro.core.ingest import load_stream
-    from repro.dist.compat import AxisType, make_mesh
     from repro.dist.sharding import cca_state_shardings
 
     H, W, V, E, chunk, timed = SCALES[scale]
@@ -48,8 +49,8 @@ def worker(scale: str, devices: int) -> dict:
     cfg = eng.cfg
     st, _ = load_stream(cfg, eng.state, edges)
 
-    mesh = make_mesh((devices, 1), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((devices, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     shards = cca_state_shardings(mesh, jax.eval_shape(lambda: st))
     st = jax.device_put(st, shards)
     step = jax.jit(lambda s: run_chunk_body(cfg, BFS, s),
@@ -71,10 +72,18 @@ def worker(scale: str, devices: int) -> dict:
 
 def run_scaling(scale: str, device_counts=(1, 2, 4, 8),
                 out_path: str = "results/bench_dist.json") -> list[dict]:
+    import jax
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"bench_dist runs fake CPU host devices in subprocesses and "
+            f"refuses a {jax.default_backend()} host, whose chip this "
+            "process holds; run the sharded path on the chips in one "
+            "process with `python chip_smoke.py --chips 4`")
     rows = []
     for d in device_counts:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
+        env["JAX_PLATFORMS"] = "cpu"
         env.setdefault("PYTHONPATH", "src")
         try:
             r = subprocess.run(

@@ -241,6 +241,8 @@ def main() -> None:
                          "results/profile/) and the resilience cost "
                          "profile (checkpoint cadence + fault deltas)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     pathlib.Path("results").mkdir(exist_ok=True)
     print("benchmark,fields...", flush=True)
     try:

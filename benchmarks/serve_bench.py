@@ -28,7 +28,7 @@ import numpy as np
 from repro.core.alloc import rhizome_rcs
 from repro.core.config import EngineConfig
 from repro.core.engine import StreamingEngine
-from repro.graph.streams import StreamSpec, make_stream
+from repro.graph.streams import StreamSpec, hashed_pair_weights, make_stream
 from repro.mq.frontdesk import FrontDesk
 from repro.mq.session import DEFAULT_SEEDS, MQSession
 from repro.obs import metrics
@@ -82,21 +82,7 @@ def _stream(p):
     spec = StreamSpec(n_vertices=p["n_vertices"], n_edges=p["n_edges"],
                       increments=p["increments"], kind="rmat",
                       symmetric=True, seed=7)
-    incs = make_stream(spec)
-    rng = np.random.default_rng(13)
-    out = []
-    for e in incs:
-        e = e.copy()
-        # mirror pairs ride adjacent in the symmetric stream layout, but
-        # increments shuffle them apart — hash each undirected pair to
-        # one weight so both directions agree
-        lo = np.minimum(e[:, 0], e[:, 1]).astype(np.int64)
-        hi = np.maximum(e[:, 0], e[:, 1]).astype(np.int64)
-        key = (lo << 21) ^ hi
-        w = (0.1 + 0.9 * ((key * 2654435761 % 1000003) / 1000003.0))
-        e[:, 2] = w.astype(np.float32).view(np.int32)
-        out.append(e)
-    return out
+    return hashed_pair_weights(make_stream(spec))
 
 
 def _serial_run(cfg, app, source, incs):

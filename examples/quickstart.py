@@ -5,8 +5,11 @@ dynamic BFS update incrementally — the paper's core demo in ~30 lines.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import EngineConfig, StreamingEngine
 from repro.core.reference import bfs_levels
+
+use_compile_cache()
 
 # an 8x8 chip of compute cells hosting 64 vertices
 cfg = EngineConfig(height=8, width=8, n_vertices=64, edge_cap=4,

@@ -3,8 +3,11 @@ per-slot cache lengths.
 
   PYTHONPATH=src python examples/serve_lm.py
 """
+from repro.compile_cache import use_compile_cache
 from repro.launch.serve import serve
 from repro.launch.train import PRESETS
+
+use_compile_cache()
 
 tokens, tput, metrics = serve(PRESETS["lm_tiny"], n_requests=6, batch=3,
                               prompt_len=8, gen_len=8, max_len=64)

@@ -8,6 +8,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import EngineConfig, StreamingEngine
 from repro.core.energy import DEFAULT as ENERGY
 from repro.core.reference import bfs_levels
@@ -28,6 +29,7 @@ def main() -> None:
                     help="virtual lanes per mesh link (DESIGN §7); "
                          ">=2 enables the escape lane + transit parking")
     args = ap.parse_args()
+    use_compile_cache()
 
     spec = StreamSpec(n_vertices=args.vertices, n_edges=args.edges,
                       increments=10, sampling=args.sampling, seed=1,

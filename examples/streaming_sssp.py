@@ -6,8 +6,11 @@ message-driven streaming dynamic algorithms").
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import EngineConfig, StreamingEngine
 from repro.core.reference import cc_labels, sssp_dists
+
+use_compile_cache()
 
 N = 256
 rng = np.random.default_rng(7)

@@ -10,11 +10,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import shape
 from repro.configs.registry import ARCHS
 from repro.data.graphs import build_graph
 from repro.models.gnn import gnn_forward, gnn_loss, init_gnn_params
 from repro.optim.adamw import AdamWConfig, adamw_update, init_adamw
+
+use_compile_cache()
 
 cfg = dataclasses.replace(ARCHS["gcn-cora"].config, d_in=64, d_out=7)
 spec = shape("demo", "gnn_full", n_nodes=512, n_edges=4096, d_feat=64)

@@ -4,7 +4,10 @@ int8-compressed gradients.  (CPU-sized; --preset lm100m on accelerators.)
 
   PYTHONPATH=src python examples/train_lm.py
 """
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import PRESETS, train
+
+use_compile_cache()
 
 params, losses = train(
     PRESETS["lm_tiny"], steps=30, batch=4, seq=64,

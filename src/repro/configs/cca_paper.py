@@ -36,6 +36,31 @@ def engine_config_for(spec) -> EngineConfig:
         io_stream_cap=max(1024, 2 * d["stream_edges"] // d["width"]))
 
 
+def stream_config_for(spec) -> EngineConfig:
+    """``engine_config_for(spec)`` on the jnp backend with the serving
+    preset's lanes and buffer depths (``benchmarks/serve_bench.py``),
+    which the full 1M-edge SBM stream needs at 32x32.  With the config's
+    ``queue_cap=32`` / ``chan_cap=8`` -- and still with ``lanes=2``,
+    ``chan_cap=16``, ``queue_cap=64`` -- the first increment's ingest
+    burst fills every action queue, park ring and data lane and the
+    livelock detector fires (DESIGN §4.2); these depths also carry a
+    Q=4 tenant batch."""
+    return dataclasses.replace(engine_config_for(spec), backend="jnp",
+                               lanes=8, chan_cap=64, queue_cap=256)
+
+
+def serve_config_for(spec) -> EngineConfig:
+    """``stream_config_for(spec)`` for a Q-batched ``MQSession`` over the
+    same stream: the IO residue is capped at 128 edges per IO cell, so
+    each increment runs as passes of at most ``128 * width`` edges, each
+    from quiescence.  In one pass, a Q=4 bfs/sssp/widest batch on the
+    100,000-edge increments collapses the 32x32 fabric (every action
+    queue at its app ceiling, every park ring full) even with doubled
+    buffers; the label-correcting tenants re-relax more the later their
+    messages arrive (DESIGN §7)."""
+    return dataclasses.replace(stream_config_for(spec), io_stream_cap=128)
+
+
 def _smoke():
     return dataclasses.replace(CCA_32, height=4, width=4, n_vertices=32,
                                ghost_slots=16, io_stream_cap=128, chunk=32)

@@ -20,7 +20,8 @@ Two execution backends share ``cycle_body`` (DESIGN §6):
   * ``backend="jnp"`` — lax chunk runners over the HBM-resident state;
   * ``backend="pallas"`` — the fused cycle megakernel
     (``kernels/cca_cycle``): K cycles per launch with the state leaves
-    held in VMEM, ``interpret=True`` fallback off-TPU.
+    held in VMEM.  It runs in Pallas interpret mode off-TPU only: on a
+    TPU backend the engine refuses it (:data:`PALLAS_ON_TPU`).
 
 The streaming driver's default fast path (``collect_traces=False``) runs
 the whole chunk loop of an increment — including the livelock detector —
@@ -264,6 +265,16 @@ def _increment_device_loop(cfg: EngineConfig, app: DiffusionApp,
                 st.stat_exec, st.stat_stall, st.stat_allocs), ring
 
 
+PALLAS_ON_TPU = (
+    "backend='pallas' does not lower on TPU: the cycle megakernel traces "
+    "all of cycle_body, whose .at[] updates are scatter/gather ops that "
+    "Mosaic refuses ('Unimplemented primitive in Pallas TPU lowering: "
+    "scatter'), and it holds the whole MachineState in VMEM (about 1.6 GB "
+    "in (8, 128) tiles at chip_32x32_50k), far beyond a TPU core's VMEM. "
+    "Use backend='jnp' on TPU; the Pallas backend runs in interpret mode "
+    "off-TPU only (DESIGN §6).")
+
+
 @dataclasses.dataclass
 class IncrementResult:
     cycles: int
@@ -283,6 +294,8 @@ class StreamingEngine:
     """Host-side driver: the accelerator-style main() of paper Listing 1."""
 
     def __init__(self, cfg: EngineConfig, app: str | DiffusionApp = "bfs"):
+        if cfg.backend == "pallas" and jax.default_backend() == "tpu":
+            raise NotImplementedError(PALLAS_ON_TPU)
         self.cfg = cfg
         self.app = APPS[app] if isinstance(app, str) else app
         cfg = dataclasses.replace(cfg, n_vals=self.app.n_vals,

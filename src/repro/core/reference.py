@@ -34,6 +34,21 @@ def sssp_dists(n: int, edges: np.ndarray, weights: np.ndarray,
     return out
 
 
+def widest_caps(n: int, edges: np.ndarray, source: int = 0) -> np.ndarray:
+    """Maximin bottleneck capacity from ``source`` by Bellman-Ford
+    iteration; weights are the float32 bit patterns in ``edges[:, 2]``."""
+    cap = np.zeros(n, np.float64)
+    cap[source] = 1e9
+    w = edges[:, 2].astype(np.int32).view(np.float32).astype(np.float64)
+    s, d = edges[:, 0], edges[:, 1]
+    while True:
+        new = cap.copy()
+        np.maximum.at(new, d, np.minimum(cap[s], w))
+        if np.array_equal(new, cap):
+            return cap.astype(np.float32)
+        cap = new
+
+
 def cc_labels(n: int, edges: np.ndarray) -> np.ndarray:
     """Min-vertex-id label per weakly connected component."""
     import networkx as nx

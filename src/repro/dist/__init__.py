@@ -7,6 +7,4 @@ behind a single programming abstraction:
 * :mod:`repro.dist.sharding` — per-family sharding rules (CCA state,
   LM, GNN, DLRM) + ``pad_to``
 * :mod:`repro.dist.pipeline` — microbatch pipeline parallelism
-* :mod:`repro.dist.compat`   — jax version shims (installed on import)
 """
-from repro.dist import compat  # noqa: F401  (installs the jax API shims)

@@ -26,8 +26,6 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (installs the jax API shims)
-
 _DIST_MESH = None
 
 

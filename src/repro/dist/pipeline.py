@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
-
 
 def split_stages(params, n_stages: int):
     """Split a layer-stacked pytree (leaves ``[L, ...]``) into
@@ -60,8 +58,11 @@ def pipelined_apply(stage_fn, stages, xs, mesh, axis: str = "pipe"):
     def per_stage(stages_l, xs_l):
         p = jax.tree.map(lambda l: l[0], stages_l)   # this device's stage
         sid = jax.lax.axis_index(axis)
-        buf0 = jnp.zeros(xs_l.shape[1:], xs_l.dtype)
-        outs0 = jnp.zeros_like(xs_l)
+        # the carry varies along the axis (each stage holds its own
+        # activation after the first ppermute), so it must start varying
+        buf0, outs0 = jax.lax.pcast(
+            (jnp.zeros(xs_l.shape[1:], xs_l.dtype), jnp.zeros_like(xs_l)),
+            axis, to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -86,6 +87,6 @@ def pipelined_apply(stage_fn, stages, xs, mesh, axis: str = "pipe"):
     stage_specs = jax.tree.map(
         lambda l: P(axis, *((None,) * (l.ndim - 1))), stages)
     rep = P(*((None,) * xs.ndim))
-    fn = compat.shard_map(per_stage, mesh=mesh,
-                          in_specs=(stage_specs, rep), out_specs=rep)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(stage_specs, rep), out_specs=rep)
     return fn(stages, xs)

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
 
 PAD_DST = np.int32(2 ** 30)
 
@@ -72,7 +71,7 @@ def spmm_partitioned(x, edge_index, n_nodes, coeff=None, mesh=None,
     if coeff is None:
         def local2(x_l, ei_l):
             return local(x_l, ei_l, None)
-        return shard_map(local2, mesh=mesh, in_specs=specs[:2],
+        return jax.shard_map(local2, mesh=mesh, in_specs=specs[:2],
+                             out_specs=P(axes, None))(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=specs,
                          out_specs=P(axes, None))(*args)
-    return shard_map(local, mesh=mesh, in_specs=specs,
-                     out_specs=P(axes, None))(*args)

@@ -183,6 +183,23 @@ def snowball_stream(edges: np.ndarray, increments: int, source: int = 0,
     return [edges[p] for p in np.split(order, cuts)]
 
 
+def hashed_pair_weights(incs: list[np.ndarray]) -> list[np.ndarray]:
+    """Re-weight stream increments with weights in (0.1, 1.0] hashed from
+    each edge's unordered vertex pair, so both directions of an undirected
+    edge agree (a symmetric stream's mirror pairs land in different
+    increments) and the weighted apps (sssp / widest) diverge from bfs."""
+    out = []
+    for e in incs:
+        e = e.copy()
+        lo = np.minimum(e[:, 0], e[:, 1]).astype(np.int64)
+        hi = np.maximum(e[:, 0], e[:, 1]).astype(np.int64)
+        key = (lo << 21) ^ hi
+        w = 0.1 + 0.9 * ((key * 2654435761 % 1000003) / 1000003.0)
+        e[:, 2] = w.astype(np.float32).view(np.int32)
+        out.append(e)
+    return out
+
+
 def make_stream(spec: StreamSpec) -> list[np.ndarray]:
     if spec.kind == "rmat":
         edges = rmat_edges(spec)

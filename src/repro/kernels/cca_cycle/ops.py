@@ -6,11 +6,12 @@
 input aliased onto its output (the state is updated in place — no
 second copy of the machine in HBM), and rebuilds the pytree.
 
-Backend selection mirrors the other kernel dirs: compiled Mosaic on
-TPU, ``interpret=True`` everywhere else so CPU CI runs the identical
-kernel semantics (Pallas interpret mode discharges the kernel into the
-surrounding XLA computation, so the fallback is still jit-compiled —
-only the VMEM residency is simulated).
+Off-TPU the kernel runs with ``interpret=True``, so CPU CI runs the
+identical kernel semantics (Pallas interpret mode discharges the kernel
+into the surrounding XLA computation, so it is still jit-compiled —
+only the VMEM residency is simulated).  On TPU, Mosaic refuses it (the
+stages' scatters) and its state exceeds VMEM, so ``StreamingEngine``
+rejects ``backend="pallas"`` there (``engine.PALLAS_ON_TPU``).
 """
 from __future__ import annotations
 

@@ -15,10 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _bag_kernel(idx_ref, row_ref, o_ref, *, L, combiner):
     l = pl.program_id(1)
@@ -52,7 +48,7 @@ def embedding_bag_fwd(table, indices, *, combiner="sum", interpret=False):
             out_specs=pl.BlockSpec((1, D), lambda b, l, idx: (b, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(flat, table)

@@ -20,10 +20,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -102,7 +98,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, bq=128, bk=128,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

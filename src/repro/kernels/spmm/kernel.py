@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _spmm_kernel(ranges_ref, dst_ref, msgs_ref, o_ref, *, bn, be):
     r = pl.program_id(0)
@@ -79,7 +75,7 @@ def scatter_spmm(msgs, dst, n_nodes, *, bn=128, be=256, interpret=False):
             out_specs=pl.BlockSpec((bn, D), lambda r, e, rng: (r, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, D), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ranges, dst, msgs)

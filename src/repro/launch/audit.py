@@ -18,7 +18,6 @@ import re
 def audit(arch, shape_name, mesh_kind="single", layers=None, top=20):
     import jax
     from repro.configs.registry import get_shape
-    from repro.dist.compat import use_mesh
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import build_plan
 
@@ -28,7 +27,7 @@ def audit(arch, shape_name, mesh_kind="single", layers=None, top=20):
     if layers is not None and bundle.family == "lm":
         ov = dict(n_layers=layers, attn_chunk=spec.dim("seq_len"))
     plan = build_plan(bundle, spec, mesh, lm_overrides=ov)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = jax.jit(plan.step, in_shardings=plan.in_shardings,
                        donate_argnums=plan.donate).lower(*plan.args).compile()
     txt = comp.as_text()

@@ -24,7 +24,6 @@ import traceback
 def run_cell(arch: str, shape_name: str, mesh_kind: str, verbose=True) -> dict:
     import jax
     from repro.configs.registry import get_shape
-    from repro.dist.compat import cost_analysis_dict, use_mesh
     from repro.launch.mesh import make_production_mesh
     from repro.launch.roofline import (model_flops, parse_collective_bytes,
                                        roofline_terms)
@@ -37,7 +36,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, verbose=True) -> dict:
     try:
         t0 = time.time()
         plan = build_plan(bundle, spec, mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(plan.step, in_shardings=plan.in_shardings,
                              donate_argnums=plan.donate)
             lowered = jitted.lower(*plan.args)
@@ -47,7 +46,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, verbose=True) -> dict:
             rec["compile_s"] = round(time.time() - t0, 2)
         mem = compiled.memory_analysis()
         print(mem)
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         print({k: v for k, v in cost.items()
                if k in ("flops", "bytes accessed")})
         rec["mem"] = dict(
@@ -71,11 +70,11 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, verbose=True) -> dict:
             for nl in (0, 1):
                 ov = dict(n_layers=nl, attn_chunk=Tk)
                 p2 = build_plan(bundle, spec, mesh, lm_overrides=ov)
-                with use_mesh(mesh):
+                with jax.set_mesh(mesh):
                     comp2 = jax.jit(
                         p2.step, in_shardings=p2.in_shardings,
                         donate_argnums=p2.donate).lower(*p2.args).compile()
-                cost2 = cost_analysis_dict(comp2)
+                cost2 = comp2.cost_analysis()
                 coll2 = parse_collective_bytes(comp2.as_text())
                 c[nl] = dict(
                     flops=float(cost2.get("flops", 0.0)),

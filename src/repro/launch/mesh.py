@@ -10,19 +10,20 @@ module never touches jax device state.
 """
 from __future__ import annotations
 
-from repro.dist.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh for CPU tests (1 device unless XLA_FLAGS overrides)."""
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple:

@@ -282,9 +282,12 @@ def flash_attention_xla(q, k, v, *, causal=True, chunk=512, q_offset=0):
             preferred_element_type=jnp.float32)
         return (m2, l2, acc2), None
 
-    m0 = jnp.full((B, Tq, H), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((B, Tq, H), jnp.float32)
-    a0 = jnp.zeros((B, Tq, H, dh), jnp.float32)
+    # carries start from q, not fresh constants, so inside a shard_map
+    # (dist/pipeline.py) they vary over the same mesh axes as the body's
+    # outputs, which the scan's carry type check requires
+    m0 = jnp.full_like(q[..., 0], -jnp.inf, jnp.float32)
+    l0 = jnp.zeros_like(q[..., 0], jnp.float32)
+    a0 = jnp.zeros_like(q, jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0),
         (jnp.moveaxis(ks, 1, 0), jnp.moveaxis(vs, 1, 0),
@@ -396,7 +399,6 @@ def _ffn_moe_ep(cfg: LMConfig, lp, x):
     per layer (ZeRO-3).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.dist.compat import shard_map
     from repro.dist.ctx import dp_axes_active, get_dist_mesh
 
     mesh = get_dist_mesh()
@@ -456,7 +458,7 @@ def _ffn_moe_ep(cfg: LMConfig, lp, x):
     wi_b = wcast(lp["moe_wi"], cfg, *wi_spec)
     wg_b = wcast(lp["moe_wg"], cfg, *wi_spec) if cfg.gated_ffn else wi_b
     wo_b = wcast(lp["moe_wo"], cfg, *wo_spec)
-    out = shard_map(
+    out = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(dp, None), P(dp, None), P(dp, None),
                   wi_spec, wi_spec, wo_spec),

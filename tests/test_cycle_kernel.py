@@ -161,3 +161,14 @@ def test_fast_path_single_jit_per_pass(monkeypatch):
     incs = make_stream(spec)
     eng, _ = run_bfs(small_cfg(), incs)
     assert len(calls) == len(incs)  # no spill -> one jit call each
+
+
+def test_pallas_backend_refused_on_tpu(monkeypatch):
+    """The megakernel cannot lower on Mosaic, so a TPU backend rejects
+    ``backend="pallas"`` at construction instead of deep in a compile."""
+    from repro.core.engine import PALLAS_ON_TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="scatter") as ei:
+        StreamingEngine(small_cfg(backend="pallas"), "bfs")
+    assert str(ei.value) == PALLAS_ON_TPU
+    StreamingEngine(small_cfg(backend="jnp"), "bfs")   # jnp still builds

@@ -12,13 +12,14 @@ import textwrap
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax, numpy as np
+    from jax.sharding import AxisType
     from repro.core.apps import BFS
     from repro.core.config import EngineConfig
     from repro.core.engine import StreamingEngine, run_chunk_body, quiescent
     from repro.core.ingest import load_stream
     from repro.core.reference import bfs_levels
-    from repro.dist.compat import AxisType, make_mesh
     from repro.dist.sharding import cca_state_shardings
 
     cfg = EngineConfig(height=8, width=8, n_vertices=64, ghost_slots=16,
@@ -44,8 +45,8 @@ SCRIPT = textwrap.dedent("""
             break
     assert bool(quiescent(sA)), "single-device run did not quiesce"
 
-    mesh = make_mesh((4, 2), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     shards = cca_state_shardings(mesh, jax.eval_shape(lambda: st0))
     # the mapping: cell rows over 'data', cell columns over 'model'
     from jax.sharding import PartitionSpec as P

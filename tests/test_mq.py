@@ -27,8 +27,9 @@ from repro.core.alloc import rhizome_rcs
 from repro.core.apps import APPS
 from repro.core.config import EngineConfig
 from repro.core.engine import StreamingEngine
-from repro.core.reference import bfs_levels, cc_labels, sssp_dists
-from repro.graph.streams import StreamSpec, make_stream
+from repro.core.reference import (bfs_levels, cc_labels, sssp_dists,
+                                  widest_caps)
+from repro.graph.streams import StreamSpec, hashed_pair_weights, make_stream
 from repro.mq.session import DEFAULT_SEEDS, MQSession, QuerySlot
 
 REF = json.loads((pathlib.Path(__file__).parent
@@ -46,37 +47,13 @@ def _mq_cfg(**kw):
 def _weighted_stream(n=128, n_edges=360, increments=2, seed=11):
     """Symmetric SBM increments with hashed per-pair weights in
     (0.1, 1.0] so sssp / widest / reliable diverge from bfs."""
-    incs = make_stream(StreamSpec(n_vertices=n, n_edges=n_edges,
-                                  increments=increments, symmetric=True,
-                                  seed=seed))
-    out = []
-    for e in incs:
-        e = e.copy()
-        lo = np.minimum(e[:, 0], e[:, 1]).astype(np.int64)
-        hi = np.maximum(e[:, 0], e[:, 1]).astype(np.int64)
-        key = (lo << 21) ^ hi
-        w = 0.1 + 0.9 * ((key * 2654435761 % 1000003) / 1000003.0)
-        e[:, 2] = w.astype(np.float32).view(np.int32)
-        out.append(e)
-    return out
+    return hashed_pair_weights(make_stream(StreamSpec(
+        n_vertices=n, n_edges=n_edges, increments=increments,
+        symmetric=True, seed=seed)))
 
 
 def _edge_floats(edges):
     return edges[:, 2].astype(np.int32).view(np.float32)
-
-
-def _widest_oracle(n, edges, source):
-    """Maximin bottleneck capacity by Bellman-Ford iteration."""
-    cap = np.zeros(n, np.float64)
-    cap[source] = 1e9
-    w = _edge_floats(edges).astype(np.float64)
-    s, d = edges[:, 0], edges[:, 1]
-    while True:
-        new = cap.copy()
-        np.maximum.at(new, d, np.minimum(cap[s], w))
-        if np.array_equal(new, cap):
-            return cap.astype(np.float32)
-        cap = new
 
 
 def _seed_single(eng, app_name, source):
@@ -153,7 +130,7 @@ def test_q8_mixed_batch_matches_single_runs():
         rtol=1e-5)
     np.testing.assert_array_equal(ses.values(4), cc_labels(n, edges))
     np.testing.assert_allclose(
-        ses.values(5), _widest_oracle(n, edges, 5), rtol=1e-6)
+        ses.values(5), widest_caps(n, edges, 5), rtol=1e-6)
 
     # per-tenant latency accounting: every settled tenant has a receipt
     for q in range(Q):
@@ -192,7 +169,7 @@ def test_mid_stream_admit_and_recycle():
     assert ses.slots[1].generation == 2
     ses.run_increment(np.zeros((0, 3), np.int32))
     np.testing.assert_allclose(
-        ses.values(1), _widest_oracle(n, edges, 5), rtol=1e-6)
+        ses.values(1), widest_caps(n, edges, 5), rtol=1e-6)
     np.testing.assert_array_equal(ses.values(0), bfs_levels(n, edges, 0))
 
     # label-flood apps cannot join once edges have streamed

@@ -6,6 +6,7 @@ import sys
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp, numpy as np
 from repro.graph.partition import partition_edges, spmm_partitioned
 from repro.graph.segment_ops import spmm

@@ -8,6 +8,7 @@ import textwrap
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.dist.pipeline import pipelined_apply, split_stages

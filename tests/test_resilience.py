@@ -248,10 +248,11 @@ def test_restore_sharded_on_fake_mesh():
     script = textwrap.dedent("""
         import os, tempfile
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import jax, numpy as np
+        from jax.sharding import AxisType
         from repro.core import EngineConfig, StreamingEngine
         from repro.core.reference import bfs_levels
-        from repro.dist.compat import AxisType, make_mesh
         from repro.dist.sharding import cca_state_shardings
         from repro.graph.streams import hub_edges
         from repro.train.checkpoint import Checkpointer
@@ -269,8 +270,8 @@ def test_restore_sharded_on_fake_mesh():
             eng.run_increment(edges[:250])
             eng.checkpoint(Checkpointer(d))
 
-            mesh = make_mesh((4, 2), ("data", "model"),
-                             axis_types=(AxisType.Auto,) * 2)
+            mesh = jax.make_mesh((4, 2), ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
             sh = cca_state_shardings(mesh, jax.eval_shape(lambda: eng.state))
             res = StreamingEngine.restore(cfg, "bfs", Checkpointer(d),
                                           shardings=sh)
