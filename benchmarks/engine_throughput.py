@@ -55,8 +55,8 @@ def bench_engine(scale: str = "ci", profile: bool = False) -> dict:
     ``profile=True`` additionally runs both backends with
     ``telemetry=True`` on the same stream (the ``--profile`` flag of
     ``benchmarks.run``): records the telemetry overhead vs the plain
-    run, asserts a non-empty frame log, and dumps the Chrome trace and
-    congestion heatmap under ``results/profile/`` (DESIGN §8).
+    run, asserts a non-empty frame log, and dumps the congestion heatmap
+    under ``results/profile/`` (DESIGN §8).
     """
     p = ENGINE_SCALES.get(scale, ENGINE_SCALES["mid"])  # paper -> mid grid
     spec = StreamSpec(n_vertices=p["n_vertices"], n_edges=p["n_edges"],
@@ -125,8 +125,8 @@ def bench_engine(scale: str = "ci", profile: bool = False) -> dict:
 def _profile_backend(p: dict, backend: str, incs, plain_wall_s: float,
                      plain_result) -> dict:
     """Telemetry-on rerun of the timed increment: overhead, frame-total
-    reconciliation against the plain run, and the exporter dumps."""
-    from repro.obs import engine_rates, write_chrome_trace, write_heatmap
+    reconciliation against the plain run, and the heatmap dump."""
+    from repro.obs import engine_rates, write_heatmap
 
     eng = StreamingEngine(_cfg(p, backend, telemetry=True), "bfs")
     eng.seed(0, 0.0)
@@ -143,8 +143,6 @@ def _profile_backend(p: dict, backend: str, incs, plain_wall_s: float,
                                        plain_result.execs), \
         (f"frame totals diverged from counters on backend={backend}: "
          f"{t} vs hops={plain_result.hops} execs={plain_result.execs}")
-    trace = write_chrome_trace(f"results/profile/trace_{backend}.json",
-                               eng.cfg, r.frames)
     heat = write_heatmap(f"results/profile/heatmap_{backend}.json",
                          eng.cfg, r.frames)
     return dict(
@@ -153,7 +151,7 @@ def _profile_backend(p: dict, backend: str, incs, plain_wall_s: float,
         frames=len(r.frames), dropped=r.frames.dropped,
         rates={k: round(v, 3) if isinstance(v, float) else v
                for k, v in engine_rates(r.frames).items()},
-        trace=trace, heatmap=heat)
+        heatmap=heat)
 
 
 def record_increments_wallclock(scale: str = "ci") -> dict:

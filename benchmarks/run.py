@@ -80,7 +80,7 @@ def bench_engine_backends(scale: str, profile: bool = False) -> None:
     """jnp vs pallas cycle-megakernel backends: throughput, bit-exact
     parity gate, livelock-detector smoke (results/bench_engine.json).
     ``--profile`` adds the telemetry-on runs: overhead, frame counts and
-    the trace/heatmap dumps under ``results/profile/`` (DESIGN §8)."""
+    the heatmap dumps under ``results/profile/`` (DESIGN §8)."""
     from benchmarks.engine_throughput import bench_engine
     r = bench_engine(scale, profile=profile)
     for backend, b in r["backends"].items():
@@ -236,8 +236,8 @@ def main() -> None:
                          "lanes|throughput|engine|faults|dist|serve|"
                          "kernels|roofline")
     ap.add_argument("--profile", action="store_true",
-                    help="telemetry-on engine runs (overhead + Chrome "
-                         "trace + congestion heatmap under "
+                    help="telemetry-on engine runs (overhead + "
+                         "congestion heatmap under "
                          "results/profile/) and the resilience cost "
                          "profile (checkpoint cadence + fault deltas)")
     args = ap.parse_args()

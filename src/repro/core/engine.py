@@ -49,6 +49,7 @@ from repro.core.routing import hop_stage, park_stage
 from repro.core.state import (TM_HOP, TM_HW_AQ, TM_L_OCC, MachineState,
                               init_state, root_addr, self_cell_grid)
 from repro.obs import frames as obs_frames
+from repro.obs import spans as obs_spans
 
 
 class CycleStats(NamedTuple):
@@ -67,11 +68,12 @@ def _rc(cfg: EngineConfig):
 
 
 def quiescent(st: MachineState) -> jax.Array:
-    return ((jnp.sum(st.aq_n) == 0) & (jnp.sum(st.ch_n) == 0)
-            & (jnp.sum(st.pk_n) == 0)
-            & ~jnp.any(st.cvalid) & (jnp.sum(st.fq_n) == 0)
-            & ~jnp.any(st.fwd_pending)
-            & (jnp.sum(st.io_n - st.io_pos) == 0))
+    with jax.named_scope("cca.quiescent"):
+        return ((jnp.sum(st.aq_n) == 0) & (jnp.sum(st.ch_n) == 0)
+                & (jnp.sum(st.pk_n) == 0)
+                & ~jnp.any(st.cvalid) & (jnp.sum(st.fq_n) == 0)
+                & ~jnp.any(st.fwd_pending)
+                & (jnp.sum(st.io_n - st.io_pos) == 0))
 
 
 def cycle_body(cfg: EngineConfig, app: DiffusionApp, st: MachineState):
@@ -80,26 +82,39 @@ def cycle_body(cfg: EngineConfig, app: DiffusionApp, st: MachineState):
     jnp chunk runners below and the Pallas cycle megakernel
     (``kernels/cca_cycle``).  Returns the per-cell activity masks as aux
     so ``cycle_step`` can build :class:`CycleStats` without recompute
-    (callers that ignore them pay nothing — XLA DCEs the masks)."""
+    (callers that ignore them pay nothing — XLA DCEs the masks).
+
+    Each stage runs under a ``jax.named_scope`` (``cca.hop``,
+    ``cca.park``, ``cca.staging``, ``cca.phase0``, ``cca.io``,
+    ``cca.telemetry``): the scope lands in the ``op_name`` metadata of
+    its ops, so a profiler trace attributes device time per stage
+    (DESIGN §8)."""
     rows, cols = _rc(cfg)
     busy0 = st.cvalid
-    if cfg.telemetry:
-        # per-lane occupancy integral at cycle entry (avg depth =
-        # TM_L_OCC / cycles); the other planes accumulate inside the
-        # stages where the grant/stall masks live (DESIGN §8)
-        st = st._replace(tm_lane=st.tm_lane.at[..., TM_L_OCC].add(st.ch_n))
-    st, hops = hop_stage(cfg, st, rows, cols)
+    with jax.named_scope("cca.hop"):
+        if cfg.telemetry:
+            # per-lane occupancy integral at cycle entry (avg depth =
+            # TM_L_OCC / cycles); the other planes accumulate inside the
+            # stages where the grant/stall masks live (DESIGN §8)
+            st = st._replace(
+                tm_lane=st.tm_lane.at[..., TM_L_OCC].add(st.ch_n))
+        st, hops = hop_stage(cfg, st, rows, cols)
     if cfg.lanes > 1:
         # re-inject parked transit messages right after the hop stage,
         # while freshly-vacated lane slots are still free (DESIGN §7);
         # with lanes == 1 nothing ever parks — skip for a bit-exact trace
-        st = park_stage(cfg, st, rows, cols)
-    st, active_a = staging_stage(cfg, app, st, rows, cols)
-    st, popped = phase0_stage(cfg, app, st, rows, cols, busy0)
-    st = io_stage(cfg, st, rows, cols)
+        with jax.named_scope("cca.park"):
+            st = park_stage(cfg, st, rows, cols)
+    with jax.named_scope("cca.staging"):
+        st, active_a = staging_stage(cfg, app, st, rows, cols)
+    with jax.named_scope("cca.phase0"):
+        st, popped = phase0_stage(cfg, app, st, rows, cols, busy0)
+    with jax.named_scope("cca.io"):
+        st = io_stage(cfg, st, rows, cols)
     if cfg.telemetry:
-        hw = jnp.stack([st.aq_n, st.pk_n], axis=-1)
-        st = st._replace(tm_hiw=jnp.maximum(st.tm_hiw, hw))
+        with jax.named_scope("cca.telemetry"):
+            hw = jnp.stack([st.aq_n, st.pk_n], axis=-1)
+            st = st._replace(tm_hiw=jnp.maximum(st.tm_hiw, hw))
     st = st._replace(cycle=st.cycle + 1,
                      stat_hops=st.stat_hops + hops)
     return st, (active_a, popped, hops)
@@ -241,15 +256,17 @@ def _increment_device_loop(cfg: EngineConfig, app: DiffusionApp,
     def body(carry):
         s, last_prog, noprog, ring = carry
         s = chunk(s)
-        # progress = an action completed OR a message hopped a link: with
-        # virtual lanes a chunk may be all-transit (messages draining
-        # through sibling lanes while a hub lane is full), so exec-only
-        # progress would false-positive; no-progress now means every
-        # lane AND every cell is stuck (DESIGN §7)
-        prog = s.stat_exec + s.stat_hops
-        noprog = jnp.where(prog == last_prog, noprog + 1, jnp.int32(0))
-        if cfg.telemetry:
-            ring = obs_frames.ring_store(ring, obs_frames.snapshot(cfg, s))
+        with jax.named_scope("cca.chunk"):
+            # progress = an action completed OR a message hopped a link:
+            # with virtual lanes a chunk may be all-transit (messages
+            # draining through sibling lanes while a hub lane is full), so
+            # exec-only progress would false-positive; no-progress now
+            # means every lane AND every cell is stuck (DESIGN §7)
+            prog = s.stat_exec + s.stat_hops
+            noprog = jnp.where(prog == last_prog, noprog + 1, jnp.int32(0))
+            if cfg.telemetry:
+                ring = obs_frames.ring_store(ring,
+                                             obs_frames.snapshot(cfg, s))
         return (s, prog, noprog, ring)
 
     if cfg.telemetry:
@@ -355,77 +372,63 @@ class StreamingEngine:
           the error re-raises with the attempt log in the message.
           A successful escalation keeps the relieved config for the
           rest of the stream (graceful degradation, not a rollback).
+
+        The whole call runs under the host span ``repro.increment``
+        (``inc`` = ``stream_pos``, ``edges``); the spans inside it carry
+        the same ``inc`` (DESIGN §8).
         """
-        if ckpt is not None:
-            self.checkpoint(ckpt, block=ckpt_block)
-        if recover is None:
-            res = self._run_increment(edges, max_cycles, collect_traces)
-            self.stream_pos += 1
-            return res
-        from repro.resilience.recover import migrate_state
-        base_cfg = self.cfg
-        # the boundary snapshot IS the recovery point: quiescent, so
-        # migrate_state can re-seat it under an escalated config
-        snapshot = jax.device_get(self.state)
-        for attempt in range(recover.max_attempts + 1):
-            try:
+        with obs_spans.increment(self.stream_pos, len(edges)):
+            if ckpt is not None:
+                self.checkpoint(ckpt, block=ckpt_block)
+            if recover is None:
                 res = self._run_increment(edges, max_cycles, collect_traces)
                 self.stream_pos += 1
                 return res
-            except LivelockError as e:
-                entry = dict(attempt=attempt, cycle=e.cycle, chunk=e.chunk,
-                             lanes=self.cfg.lanes,
-                             queue_cap=self.cfg.queue_cap,
-                             wedge=str(e))
-                self.recovery_log.append(entry)
-                if attempt >= recover.max_attempts:
-                    log = "\n".join(
-                        f"  attempt {n['attempt']}: lanes={n['lanes']} "
-                        f"queue_cap={n['queue_cap']} wedged at cycle "
-                        f"{n['cycle']}" for n in self.recovery_log)
-                    raise LivelockError(
-                        f"{e}\nrecovery budget exhausted "
-                        f"({recover.max_attempts} escalations):\n{log}",
-                        cycle=e.cycle, chunk=e.chunk,
-                        frames=e.frames) from e
-                new_cfg = recover.escalate(base_cfg, attempt + 1)
-                delay = recover.backoff_s * (2 ** attempt)
-                entry["backoff_s"] = delay
-                entry["escalated_to"] = dict(lanes=new_cfg.lanes,
-                                             queue_cap=new_cfg.queue_cap)
-                if delay:
-                    time.sleep(delay)
-                self.cfg = new_cfg
-                self.state = migrate_state(new_cfg, self.app, snapshot)
-                self._ingest_budget = None  # re-learn under the new sizing
+            from repro.resilience.recover import migrate_state
+            base_cfg = self.cfg
+            # the boundary snapshot IS the recovery point: quiescent, so
+            # migrate_state can re-seat it under an escalated config
+            snapshot = jax.device_get(self.state)
+            for attempt in range(recover.max_attempts + 1):
+                try:
+                    res = self._run_increment(edges, max_cycles,
+                                              collect_traces)
+                    self.stream_pos += 1
+                    return res
+                except LivelockError as e:
+                    entry = dict(attempt=attempt, cycle=e.cycle, chunk=e.chunk,
+                                 lanes=self.cfg.lanes,
+                                 queue_cap=self.cfg.queue_cap,
+                                 wedge=str(e))
+                    self.recovery_log.append(entry)
+                    if attempt >= recover.max_attempts:
+                        log = "\n".join(
+                            f"  attempt {n['attempt']}: lanes={n['lanes']} "
+                            f"queue_cap={n['queue_cap']} wedged at cycle "
+                            f"{n['cycle']}" for n in self.recovery_log)
+                        raise LivelockError(
+                            f"{e}\nrecovery budget exhausted "
+                            f"({recover.max_attempts} escalations):\n{log}",
+                            cycle=e.cycle, chunk=e.chunk,
+                            frames=e.frames) from e
+                    new_cfg = recover.escalate(base_cfg, attempt + 1)
+                    delay = recover.backoff_s * (2 ** attempt)
+                    entry["backoff_s"] = delay
+                    entry["escalated_to"] = dict(lanes=new_cfg.lanes,
+                                                 queue_cap=new_cfg.queue_cap)
+                    if delay:
+                        time.sleep(delay)
+                    self.cfg = new_cfg
+                    self.state = migrate_state(new_cfg, self.app, snapshot)
+                    self._ingest_budget = None  # re-learn under the new sizing
 
     def _run_increment(self, edges, max_cycles, collect_traces):
         cfg = self.cfg
         limit = max_cycles or cfg.max_cycles
         self.state, spill = load_stream(cfg, self.state, edges,
                                         limit=self._ingest_limit())
-        self.state = self.state._replace(stat_hops=jnp.int32(0),
-                                         stat_exec=jnp.int32(0),
-                                         stat_stall=jnp.int32(0),
-                                         stat_allocs=jnp.int32(0))
-        if cfg.qbatch > 1:
-            # per-query relax counters reset per increment so the mq
-            # session layer reads them as this-increment activity (§10);
-            # qlast persists — it is the absolute settle cycle per slot
-            self.state = self.state._replace(
-                qchg=jnp.zeros_like(self.state.qchg))
-        if cfg.faults is not None:
-            # fault counters reset with the stat_* scalars: the §9 loss
-            # detector reconciles per increment
-            self.state = self.state._replace(
-                flt=jnp.zeros_like(self.state.flt))
-        if cfg.telemetry:
-            # the telemetry planes reset with the stat_* scalars so the
-            # final frame of the increment reconciles exactly (DESIGN §8)
-            self.state = self.state._replace(
-                tm_cell=jnp.zeros_like(self.state.tm_cell),
-                tm_lane=jnp.zeros_like(self.state.tm_lane),
-                tm_hiw=jnp.zeros_like(self.state.tm_hiw))
+        with obs_spans.span("repro.reset_counters"):
+            self._reset_counters()
         if collect_traces:
             return self._run_increment_traced(spill, limit)
         rings = []
@@ -458,17 +461,48 @@ class StreamingEngine:
             cycles, *counters,
             np.zeros(0, np.int32), np.zeros(0, np.int32), frames)
 
+    def _reset_counters(self):
+        """Zero the per-increment counters before the device loop."""
+        cfg = self.cfg
+        self.state = self.state._replace(stat_hops=jnp.int32(0),
+                                         stat_exec=jnp.int32(0),
+                                         stat_stall=jnp.int32(0),
+                                         stat_allocs=jnp.int32(0))
+        if cfg.qbatch > 1:
+            # per-query relax counters reset per increment so the mq
+            # session layer reads them as this-increment activity (§10);
+            # qlast persists — it is the absolute settle cycle per slot
+            self.state = self.state._replace(
+                qchg=jnp.zeros_like(self.state.qchg))
+        if cfg.faults is not None:
+            # fault counters reset with the stat_* scalars: the §9 loss
+            # detector reconciles per increment
+            self.state = self.state._replace(
+                flt=jnp.zeros_like(self.state.flt))
+        if cfg.telemetry:
+            # the telemetry planes reset with the stat_* scalars so the
+            # final frame of the increment reconciles exactly (DESIGN §8)
+            self.state = self.state._replace(
+                tm_cell=jnp.zeros_like(self.state.tm_cell),
+                tm_lane=jnp.zeros_like(self.state.tm_lane),
+                tm_hiw=jnp.zeros_like(self.state.tm_hiw))
+
     def _device_passes(self, cfg, spill, limit, rings, cycles=0):
         """Sync-free device passes until quiescence with the spill fully
         drained, or until the cycle/livelock budget trips.  Returns
         ``(cycles, quiescent, noprog, (hops, execs, stalls, allocs),
-        spill)`` — counters are the increment-cumulative stat scalars."""
+        spill)`` — counters are the increment-cumulative stat scalars.
+        Each pass runs under the host spans ``repro.dispatch`` (the
+        device loop's call) and ``repro.wait`` (its readback, where the
+        host waits for the device)."""
         while True:
-            self.state, out, ring = _increment_device_loop(
-                cfg, self.app, self.state, limit - cycles)
+            with obs_spans.span("repro.dispatch"):
+                self.state, out, ring = _increment_device_loop(
+                    cfg, self.app, self.state, limit - cycles)
             # exactly ONE batched transfer per pass: the scalar record
             # and the frame ring come back together
-            out, ring = jax.device_get((out, ring))
+            with obs_spans.span("repro.wait"):
+                out, ring = jax.device_get((out, ring))
             ran, q, noprog, hops, execs, stalls, allocs = \
                 (int(x) for x in out)
             if ring is not None:

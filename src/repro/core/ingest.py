@@ -20,6 +20,7 @@ from repro.core.msg import (OP_INSERT_EDGE, OP_REPAIR, make_msg, pad_msg,
 from repro.core.routing import (deliver, manhattan_hops, msg_lane,
                                 yx_target_buffer)
 from repro.core.state import MachineState, TM_IO, root_addr
+from repro.obs.spans import span
 
 
 def load_stream(cfg: EngineConfig, st: MachineState, edges: np.ndarray,
@@ -39,32 +40,40 @@ def load_stream(cfg: EngineConfig, st: MachineState, edges: np.ndarray,
     backpressure knob (DESIGN §9): the engine lowers the limit when the
     ``tm_hiw`` action-queue hi-water mark shows the fabric saturating,
     so ingest throttles instead of wedging the machine.
+
+    Runs under the host span ``repro.load_stream``, with the children
+    ``repro.load_stream.fetch`` (the device-to-host read of the IO
+    buffers, which waits for the device) and
+    ``repro.load_stream.upload`` (the host-to-device copies).
     """
     IO, L = cfg.io_cells, cfg.io_stream_cap
-    io_edges = np.asarray(st.io_edges)
-    io_n = np.asarray(st.io_n).copy()
-    io_pos = np.asarray(st.io_pos).copy()
-    # compact: drop consumed prefix
-    new_edges = np.zeros_like(io_edges)
-    new_n = np.zeros_like(io_n)
-    for i in range(IO):
-        rem = io_edges[i, io_pos[i]:io_n[i]]
-        new_edges[i, :len(rem)] = rem
-        new_n[i] = len(rem)
-    edges = np.asarray(edges, np.int32).reshape(-1, 3)
-    spill = []
-    admitted = 0
-    for k, e in enumerate(edges):
-        i = k % IO
-        if new_n[i] >= L or (limit is not None and admitted >= limit):
-            spill.append(e)
-            continue
-        new_edges[i, new_n[i]] = e
-        new_n[i] += 1
-        admitted += 1
-    st = st._replace(io_edges=jnp.asarray(new_edges),
-                     io_n=jnp.asarray(new_n),
-                     io_pos=jnp.zeros_like(st.io_pos))
+    with span("repro.load_stream"):
+        with span("repro.load_stream.fetch"):
+            io_edges = np.asarray(st.io_edges)
+            io_n = np.asarray(st.io_n).copy()
+            io_pos = np.asarray(st.io_pos).copy()
+        # compact: drop consumed prefix
+        new_edges = np.zeros_like(io_edges)
+        new_n = np.zeros_like(io_n)
+        for i in range(IO):
+            rem = io_edges[i, io_pos[i]:io_n[i]]
+            new_edges[i, :len(rem)] = rem
+            new_n[i] = len(rem)
+        edges = np.asarray(edges, np.int32).reshape(-1, 3)
+        spill = []
+        admitted = 0
+        for k, e in enumerate(edges):
+            i = k % IO
+            if new_n[i] >= L or (limit is not None and admitted >= limit):
+                spill.append(e)
+                continue
+            new_edges[i, new_n[i]] = e
+            new_n[i] += 1
+            admitted += 1
+        with span("repro.load_stream.upload"):
+            st = st._replace(io_edges=jnp.asarray(new_edges),
+                             io_n=jnp.asarray(new_n),
+                             io_pos=jnp.zeros_like(st.io_pos))
     return st, (np.stack(spill) if spill
                 else np.zeros((0, 3), np.int32))
 

@@ -38,6 +38,7 @@ from repro.core.engine import StreamingEngine
 from repro.core.msg import MSG_WORDS, OP_APP
 from repro.core.state import root_addr
 from repro.mq.app import batch_app
+from repro.obs.spans import span
 
 # default seed value per app family: the value a source vertex starts
 # from (BFS/SSSP distance 0; widest bottleneck +INF; reliable prob 1)
@@ -204,34 +205,38 @@ class MQSession:
 
     def run_increment(self, edges, **kw):
         """Ingest one edge increment, run to global quiescence, then fold
-        the per-slot relax counters into each tenant's lifecycle."""
+        the per-slot relax counters into each tenant's lifecycle, under
+        the host span ``repro.mq.fold`` (its ``inc`` is the engine's
+        increment's; the ``qchg``/``qlast`` readbacks wait for the
+        device)."""
         edges = np.asarray(edges, np.int32).reshape(-1, 3)
         res = self.eng.run_increment(edges, **kw)
-        self.edges_seen += len(edges)
-        qchg = np.asarray(self.eng.state.qchg)
-        qlast = np.asarray(self.eng.state.qlast)
-        end_cycle = int(self.eng.state.cycle)
-        for q, s in enumerate(self.slots):
-            if s.state == "free":
-                continue
-            s.increments += 1
-            if self.qbatch == 1:
-                # no per-slot counters at qbatch == 1 (they are [1]
-                # dummies, kept un-updated for the bit-exact trace);
-                # global quiescence IS the query's quiescence, with the
-                # boundary cycle as a conservative settle point
-                changed = 1 if len(edges) else 0
-                last = end_cycle
-            else:
-                changed = int(qchg[q])
-                last = int(qlast[q])
-            if s.state == "active" and changed == 0:
-                s.state = "settled"
-                s.settle_cycle = last
-            elif s.state == "settled" and changed > 0:
-                # the evolving graph re-activated a settled tenant; its
-                # first-settle latency is already recorded
-                s.state = "active"
+        with span("repro.mq.fold", inc=self.eng.stream_pos - 1):
+            self.edges_seen += len(edges)
+            qchg = np.asarray(self.eng.state.qchg)
+            qlast = np.asarray(self.eng.state.qlast)
+            end_cycle = int(self.eng.state.cycle)
+            for q, s in enumerate(self.slots):
+                if s.state == "free":
+                    continue
+                s.increments += 1
+                if self.qbatch == 1:
+                    # no per-slot counters at qbatch == 1 (they are [1]
+                    # dummies, kept un-updated for the bit-exact trace);
+                    # global quiescence IS the query's quiescence, with
+                    # the boundary cycle as a conservative settle point
+                    changed = 1 if len(edges) else 0
+                    last = end_cycle
+                else:
+                    changed = int(qchg[q])
+                    last = int(qlast[q])
+                if s.state == "active" and changed == 0:
+                    s.state = "settled"
+                    s.settle_cycle = last
+                elif s.state == "settled" and changed > 0:
+                    # the evolving graph re-activated a settled tenant;
+                    # its first-settle latency is already recorded
+                    s.state = "active"
         return res
 
     # ---------------- readback / retirement ----------------

@@ -9,9 +9,11 @@ bypassing it:
 * ``flight``  — the livelock flight recorder: post-mortem wedge
   analysis over the last recorded frames and the rendered
   "who is wedged" report attached to :class:`LivelockError`;
-* ``export``  — Chrome ``trace_event`` JSON (one track per stage, one
-  per link lane) and the congestion-heatmap dump consumed by
+* ``export``  — the congestion-heatmap dump consumed by
   ``benchmarks/report.py``;
+* ``spans``   — host spans on the ``jax.profiler`` clock around the
+  steps of each increment (the device side carries one
+  ``jax.named_scope`` per machine stage, ``cca.*``);
 * ``metrics`` — small latency/throughput summary helpers used by the
   serving surface (``launch/serve.py``).
 
@@ -21,8 +23,7 @@ cycle stages when ``EngineConfig.telemetry`` is on — both backends (jnp
 chunk runners and the Pallas cycle megakernel) inherit them through
 ``cycle_body`` with zero extra host syncs.
 """
-from repro.obs.export import (chrome_trace, congestion_heatmap,
-                              write_chrome_trace, write_heatmap)
+from repro.obs.export import congestion_heatmap, write_heatmap
 from repro.obs.flight import (render_wedge_report, wedged_cells,
                               wedged_lanes)
 from repro.obs.frames import (FS_ALLOCS, FS_BACKLOG, FS_CYCLE, FS_EXEC,
@@ -30,12 +31,13 @@ from repro.obs.frames import (FS_ALLOCS, FS_BACKLOG, FS_CYCLE, FS_EXEC,
                               FrameLog, FrameRing, init_ring, ring_store,
                               snapshot)
 from repro.obs.metrics import engine_rates, render_summary, summarize
+from repro.obs.spans import increment, span
 
 __all__ = [
     "FrameLog", "FrameRing", "init_ring", "ring_store", "snapshot",
     "FS_CYCLE", "FS_HOPS", "FS_EXEC", "FS_STALL", "FS_ALLOCS",
     "FS_BACKLOG", "FS_INFLIGHT", "FS_QUIESCENT",
-    "chrome_trace", "congestion_heatmap", "write_chrome_trace",
-    "write_heatmap", "render_wedge_report", "wedged_cells", "wedged_lanes",
-    "engine_rates", "render_summary", "summarize",
+    "congestion_heatmap", "write_heatmap",
+    "render_wedge_report", "wedged_cells", "wedged_lanes",
+    "engine_rates", "render_summary", "summarize", "increment", "span",
 ]

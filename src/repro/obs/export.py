@@ -1,19 +1,11 @@
-"""Telemetry exporters (DESIGN §8).
+"""Telemetry exporter (DESIGN §8).
 
-Two render targets for a :class:`repro.obs.FrameLog`:
-
-* :func:`chrome_trace` — Chrome ``trace_event`` JSON (load in
-  ``chrome://tracing`` / Perfetto): one counter track per pipeline
-  stage (chip-wide per-chunk activity) and one per virtual lane
-  (occupancy + grants + blocked, aggregated over the mesh), with the
-  machine cycle as the timebase (1 cycle = 1 "us");
-* :func:`congestion_heatmap` — per-cell [H,W] planes of the increment's
-  cumulative activity (arrivals, execs, stalls, lane occupancy
-  integral, blocked cycles, queue hi-water marks), the JSON dump that
-  ``benchmarks/report.py --section congestion`` renders.
-
-Both are pure dict builders over host numpy; ``write_*`` helpers dump
-them to JSON files under ``results/profile/``.
+:func:`congestion_heatmap` renders a :class:`repro.obs.FrameLog` as
+per-cell [H,W] planes of the increment's cumulative activity (arrivals,
+execs, stalls, lane occupancy integral, blocked cycles, queue hi-water
+marks), the JSON dump that ``benchmarks/report.py --section
+congestion`` renders; :func:`write_heatmap` writes it under
+``results/profile/``.
 """
 from __future__ import annotations
 
@@ -31,50 +23,6 @@ from repro.obs.frames import FS_CYCLE, FrameLog
 STAGE_NAMES = ("exec", "alloc", "stall", "hop", "stage",
                "park", "unpark", "io", "bcast")
 assert len(STAGE_NAMES) == N_TM_STAGES
-
-_DIR_NAMES = ("N", "S", "W", "E")
-
-
-def chrome_trace(cfg: EngineConfig, frames: FrameLog) -> dict:
-    """Chrome ``trace_event`` counter tracks from the frame log.
-
-    Counter semantics: each sample is the PER-CHUNK activity (delta of
-    the cumulative plane between consecutive frames), stamped at the
-    frame's machine cycle.  Stage tracks sum over the mesh; lane tracks
-    sum each ``(direction, lane)`` pair over the mesh so a wedged escape
-    lane shows up as a flat-lining ``lane/W0 grants`` counter.
-    """
-    d = frames.deltas()
-    cyc = frames.scal[:, FS_CYCLE]
-    if frames.dropped:
-        cyc = cyc[1:]                       # deltas() dropped frame 0
-    events = []
-
-    def counter(name, ts, args):
-        events.append(dict(name=name, ph="C", ts=int(ts), pid=0, tid=0,
-                           args={k: int(v) for k, v in args.items()}))
-
-    cell = d["cell"].sum(axis=(1, 2))        # [N, N_TM_STAGES]
-    for i, t in enumerate(cyc):
-        for s, name in enumerate(STAGE_NAMES):
-            counter(f"stage/{name}", t, {name: cell[i, s]})
-    lane = d["lane"].sum(axis=(1, 2))        # [N, 4, L, N_TM_LANE]
-    occ = frames.ch_n.sum(axis=(1, 2))       # [N, 4, L] instantaneous
-    if frames.dropped:
-        occ = occ[1:]
-    L = lane.shape[2]
-    for i, t in enumerate(cyc):
-        for dd in range(4):
-            for l in range(L):
-                counter(f"lane/{_DIR_NAMES[dd]}{l}", t, {
-                    "occ": occ[i, dd, l],
-                    "grants": lane[i, dd, l, TM_L_GRANT],
-                    "blocked": lane[i, dd, l, TM_L_BLOCK]})
-    return dict(traceEvents=events, displayTimeUnit="ms",
-                metadata=dict(timebase="1 trace us = 1 machine cycle",
-                              grid=f"{cfg.height}x{cfg.width}",
-                              lanes=cfg.lanes, frames=len(frames),
-                              dropped=frames.dropped))
 
 
 def congestion_heatmap(cfg: EngineConfig, frames: FrameLog) -> dict:
@@ -99,13 +47,6 @@ def congestion_heatmap(cfg: EngineConfig, frames: FrameLog) -> dict:
         lane_grants=plane(lane[..., TM_L_GRANT].sum(axis=(-2, -1))),
         aq_hiwater=plane(hiw[..., TM_HW_AQ]),
         pk_hiwater=plane(hiw[..., TM_HW_PK]))
-
-
-def write_chrome_trace(path, cfg: EngineConfig, frames: FrameLog) -> str:
-    p = pathlib.Path(path)
-    p.parent.mkdir(parents=True, exist_ok=True)
-    p.write_text(json.dumps(chrome_trace(cfg, frames)))
-    return str(p)
 
 
 def write_heatmap(path, cfg: EngineConfig, frames: FrameLog) -> str:

@@ -1,5 +1,5 @@
 """Observability layer (DESIGN §8): telemetry planes, frame ring,
-flight recorder, exporters.
+flight recorder, heatmap exporter.
 
 Pins the four contracts of ``repro.obs``:
 
@@ -14,8 +14,8 @@ Pins the four contracts of ``repro.obs``:
 * the livelock flight recorder raises a structured
   :class:`LivelockError` carrying the frame log and naming the wedged
   cells/lanes of the known §4.2 hub deadlock;
-* the exporters (Chrome trace / congestion heatmap) preserve the
-  totals they re-aggregate.
+* the congestion-heatmap exporter preserves the totals it
+  re-aggregates.
 """
 import json
 import pathlib
@@ -27,9 +27,8 @@ from repro.core import EngineConfig, StreamingEngine
 from repro.core.engine import LivelockError
 from repro.core.state import TM_EXEC, TM_HOP, TM_IO
 from repro.graph.streams import StreamSpec, hub_edges, make_stream
-from repro.obs import (FS_CYCLE, FrameLog, chrome_trace, congestion_heatmap,
-                       engine_rates, summarize, wedged_cells, wedged_lanes)
-from repro.obs.export import STAGE_NAMES
+from repro.obs import (FS_CYCLE, FrameLog, congestion_heatmap, engine_rates,
+                       summarize, wedged_cells, wedged_lanes)
 
 ONE = np.float32(1.0).view(np.int32)
 REF = json.loads((pathlib.Path(__file__).parent
@@ -169,29 +168,12 @@ def test_livelock_without_telemetry_is_structured_but_frameless():
     assert "livelock" in str(ei.value)     # back-compat substring
 
 
-# ----------------------------- exporters ---------------------------------
+# ----------------------------- exporter ----------------------------------
 
 def _frames(backend="jnp"):
     eng, incs = _ref_engine(backend, telemetry=True, frame_ring=16)
     r = eng.run_increment(incs[0], max_cycles=500_000)
     return eng.cfg, r
-
-
-def test_chrome_trace_structure_and_totals():
-    cfg, r = _frames()
-    tr = chrome_trace(cfg, r.frames)
-    evs = tr["traceEvents"]
-    assert evs and all(e["ph"] == "C" for e in evs)
-    names = {e["name"] for e in evs}
-    assert {f"stage/{n}" for n in STAGE_NAMES} <= names
-    assert {f"lane/{d}0" for d in "NSWE"} <= names
-    # counter deltas sum back to the increment totals
-    hops = sum(e["args"]["hop"] for e in evs if e["name"] == "stage/hop")
-    execs = sum(e["args"]["exec"] for e in evs if e["name"] == "stage/exec")
-    assert (hops, execs) == (r.hops, r.execs)
-    # timestamps are machine cycles, monotone per track
-    ts = [e["ts"] for e in evs if e["name"] == "stage/hop"]
-    assert ts == sorted(ts)
 
 
 def test_congestion_heatmap_totals_and_report_render():
