@@ -28,6 +28,7 @@ import numpy as np
 from repro.core.alloc import rhizome_rcs
 from repro.core.config import EngineConfig
 from repro.core.engine import StreamingEngine
+from repro.core.state import vals_index
 from repro.graph.streams import StreamSpec, hashed_pair_weights, make_stream
 from repro.mq.frontdesk import FrontDesk
 from repro.mq.session import DEFAULT_SEEDS, MQSession
@@ -93,8 +94,8 @@ def _serial_run(cfg, app, source, incs):
         ks = np.arange(cfg.rhizome_cap, dtype=np.int64)[:, None]
         r, c, s = rhizome_rcs(eng.cfg, vids, ks)
         labels = np.broadcast_to(vids.astype(np.float32), r.shape)
-        eng.state = eng.state._replace(
-            vals=eng.state.vals.at[r, c, s, 0].set(labels))
+        at = vals_index(eng.cfg, r, c, s)
+        eng.state = eng.state._replace(vals=eng.state.vals.at[at].set(labels))
     else:
         eng.seed(source, DEFAULT_SEEDS[app])
     cycles = 0

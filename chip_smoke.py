@@ -232,7 +232,7 @@ def sharded_phase(cfg, edges) -> None:
                          axis_types=(AxisType.Auto,) * 2,
                          devices=jax.devices()[:4])
     shards = cca_state_shardings(mesh, jax.eval_shape(lambda: st0))
-    if shards.vals.spec != P("data", "model", None, None):
+    if shards.vals.spec != P("data", "model", None):
         raise SystemExit(f"sharded phase: vals spec {shards.vals.spec}")
     sB = jax.device_put(st0, shards)
     per_dev = {s.device.id: s.data.shape for s in sB.vals.addressable_shards}

@@ -194,6 +194,8 @@ class EngineConfig:
         assert self.qbatch <= 32, \
             "qbatch > 32 overflows the int32 qsel bitmask (msg word 3, " \
             "DESIGN §10); shard tenants over several sessions instead"
+        assert self.qbatch > 1 or self.n_vals == 1, \
+            "qbatch == 1 keeps one value per slot (vals is [H,W,S])"
         if self.qbatch > 1:
             assert self.faults is None, \
                 "faults + qbatch > 1 is unsupported: the OP_REPAIR io " \

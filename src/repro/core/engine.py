@@ -47,7 +47,8 @@ from repro.core.exec_stage import phase0_stage, staging_stage
 from repro.core.ingest import io_stage, load_stream
 from repro.core.routing import hop_stage, park_stage
 from repro.core.state import (TM_HOP, TM_HW_AQ, TM_L_OCC, MachineState,
-                              init_state, root_addr, self_cell_grid)
+                              init_state, root_addr, self_cell_grid,
+                              vals_index)
 from repro.obs import frames as obs_frames
 from repro.obs import spans as obs_spans
 
@@ -335,7 +336,8 @@ class StreamingEngine:
         ks = np.arange(cfg.rhizome_cap)
         r, c, s = rhizome_rcs(cfg, vid, ks)      # [R] each: one scatter
         self.state = self.state._replace(
-            vals=self.state.vals.at[r, c, s, val_idx].set(value))
+            vals=self.state.vals.at[vals_index(cfg, r, c, s, q=val_idx)]
+            .set(value))
 
     # -- stream one increment of edges and run to quiescence --
     def run_increment(self, edges: np.ndarray,
@@ -604,7 +606,7 @@ class StreamingEngine:
         vids = np.arange(cfg.n_vertices, dtype=np.int64)[None, :]
         ks = np.arange(cfg.rhizome_cap, dtype=np.int64)[:, None]
         r, c, s = rhizome_rcs(cfg, vids, ks)                     # [R, n]
-        vals = np.asarray(self.state.vals[..., 0])[r, c, s]
+        vals = np.asarray(self.state.vals[vals_index(cfg, ...)])[r, c, s]
         on = np.asarray(self.state.rhz_on)[r, c, s]
         on[0, :] = True                # canonical root is always live
         v = functools.reduce(app.combine, vals)                  # [n]
@@ -768,7 +770,8 @@ class StreamingEngine:
         vids = np.arange(n, dtype=np.int64)[None, :]
         ks = np.arange(cfg.rhizome_cap, dtype=np.int64)[:, None]
         r, c, s = rhizome_rcs(cfg, vids, ks)                     # [R, n]
-        v = np.asarray(self.state.vals[..., val_idx])[r, c, s]
+        v = np.asarray(self.state.vals[vals_index(cfg, ..., q=val_idx)])[
+            r, c, s]
         return functools.reduce(combine or self.app.combine, v)
 
     def vertex_object_stats(self) -> dict:

@@ -302,7 +302,11 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
     dst, a0, a1 = m[..., 1], m[..., 2], m[..., 3]
     slot = dst % S
 
-    vals_s = sel(st.vals, slot)             # [H,W,VN]
+    # [H,W,VN]: the apps relax a value vector; at qbatch == 1 the
+    # stored leaf is [H,W,S] (state.vals_index) and VN == 1
+    vals_s = sel(st.vals, slot)
+    if QB == 1:
+        vals_s = vals_s[..., None]
     ne = sel(st.nedges, slot)
     gs = sel(st.gstate, slot)
     fqn = sel(st.fq_n, slot)
@@ -418,7 +422,8 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
         new_vals, changed_q = app.relax(vals_s, inc)
         changed_q = changed_q & relaxing[..., None]       # [H,W,QB]
         changed = jnp.any(changed_q, axis=-1)
-    vals = put(st.vals, slot, new_vals, relaxing)
+    vals = put(st.vals, slot, new_vals[..., 0] if QB == 1 else new_vals,
+               relaxing)
     # a changed relax at a canonical root of a multi-root vertex also
     # broadcasts to the R-1 sibling rhizomes — in parallel, replacing the
     # serial forward walk of the chain design (DESIGN §4.5).  The root
@@ -461,8 +466,7 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
     alc_full = is_alc & ~(st.nfree < S)
     g_new = st.nfree
     if QB == 1:
-        gseed = (jnp.full((H, W, cfg.n_vals), jnp.float32(app.init_val))
-                 .at[..., 0].set(i2f(a1)))
+        gseed = i2f(a1)
     else:
         # the allocation request carried the requester's whole value
         # vector (word 3 + extension words), so the ghost starts synced

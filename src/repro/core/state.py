@@ -61,7 +61,8 @@ N_TM_HIW = 2
 
 class MachineState(NamedTuple):
     # --- RPVO slot storage [H, W, S, ...] ---
-    vals: jax.Array        # [H,W,S,VN] f32  application values (BFS level, ...)
+    vals: jax.Array        # [H,W,S]    f32  application values (BFS level,
+                           # ...); [H,W,S,Q] at qbatch > 1 (vals_index)
     nedges: jax.Array      # [H,W,S]    i32  edges in this RPVO node
     edst: jax.Array        # [H,W,S,E]  i32  edge dst = root addr of dst vertex
     ew: jax.Array          # [H,W,S,E]  f32  edge weight
@@ -151,19 +152,19 @@ def init_state(cfg: EngineConfig,
     """
     cfg.validate()
     H, W, S, E = cfg.height, cfg.width, cfg.slots, cfg.edge_cap
-    VN, FQ, Q = cfg.n_vals, cfg.futq_cap, cfg.queue_cap
+    FQ, Q = cfg.futq_cap, cfg.queue_cap
     VL, LC = cfg.lanes, cfg.lane_capacity
     IO, L = cfg.io_cells, cfg.io_stream_cap
     QB, WM = cfg.qbatch, cfg.msg_words
     z32 = lambda *s: jnp.zeros(s, jnp.int32)
-    vals = jnp.full((H, W, S, VN), jnp.float32(init_vals))
-    # qbatch > 1 widens the emission snapshot and the forward register
-    # with the query axis (DESIGN §10); qbatch == 1 keeps the classic
-    # scalar shapes so the pre-mq trace is unchanged
+    # qbatch > 1 widens the vertex values, the emission snapshot and the
+    # forward register with the query axis (DESIGN §10); qbatch == 1
+    # keeps scalar shapes, so no size-1 value axis lands on the TPU's
+    # 128 lanes (a 128-fold padded copy of vals every cycle)
     fwd_shape = (H, W, S) if QB == 1 else (H, W, S, QB)
     cemit_shape = (H, W) if QB == 1 else (H, W, QB)
     return MachineState(
-        vals=vals,
+        vals=jnp.full(fwd_shape, jnp.float32(init_vals)),
         nedges=z32(H, W, S),
         edst=jnp.full((H, W, S, E), -1, jnp.int32),
         ew=jnp.zeros((H, W, S, E), jnp.float32),
@@ -203,6 +204,15 @@ def init_state(cfg: EngineConfig,
 
 
 # ---------------- addressing helpers ----------------
+
+def vals_index(cfg: EngineConfig, *lead, q: int = 0) -> tuple:
+    """Index of query ``q``'s values in the ``vals`` leaf: ``lead``
+    alone at qbatch == 1, where ``vals`` is ``[H,W,S]``, and ``lead``
+    plus ``q`` above, where it is ``[H,W,S,Q]``.  ``vals[vals_index(cfg,
+    ..., q=q)]`` is query q's ``[H,W,S]`` plane; ``vals.at[vals_index(cfg,
+    r, c, s, q=q)]`` its value at (r, c, s)."""
+    return lead if cfg.qbatch == 1 else (*lead, q)
+
 
 def root_addr(cfg: EngineConfig, vid):
     """Global address of vertex vid's RPVO root."""

@@ -36,7 +36,7 @@ from repro.core.apps import APPS, DiffusionApp
 from repro.core.config import EngineConfig
 from repro.core.engine import StreamingEngine
 from repro.core.msg import MSG_WORDS, OP_APP
-from repro.core.state import root_addr
+from repro.core.state import root_addr, vals_index
 from repro.mq.app import batch_app
 from repro.obs.spans import span
 
@@ -149,7 +149,7 @@ class MQSession:
             else self.composite.fwd_neutral))
         st = eng.state
         if self.qbatch == 1:
-            st = st._replace(vals=st.vals.at[..., 0].set(init),
+            st = st._replace(vals=st.vals.at[...].set(init),
                              fwd_val=st.fwd_val.at[...].set(neutral))
         else:
             st = st._replace(
@@ -166,9 +166,9 @@ class MQSession:
         ks = np.arange(cfg.rhizome_cap, dtype=np.int64)[:, None]
         r, c, s = rhizome_rcs(cfg, vids, ks)
         labels = np.broadcast_to(vids.astype(np.float32), r.shape)
-        vi = slot if self.qbatch > 1 else 0
         eng.state = eng.state._replace(
-            vals=eng.state.vals.at[r, c, s, vi].set(jnp.asarray(labels)))
+            vals=eng.state.vals.at[vals_index(cfg, r, c, s, q=slot)]
+            .set(jnp.asarray(labels)))
 
     def _inject_seed(self, slot: int, source: int, seed: float):
         """Push one qsel-masked OP_APP onto the action queue of the
@@ -245,8 +245,7 @@ class MQSession:
         """Per-query values: the slot's own plane, root-combined with the
         slot app's OWN reduce (min for min-monotone, max for widest)."""
         a = self.slot_apps[slot]
-        return self.eng.values(n, val_idx=slot if self.qbatch > 1 else 0,
-                               combine=a.combine)
+        return self.eng.values(n, val_idx=slot, combine=a.combine)
 
     def settled_slots(self) -> "list[int]":
         return [q for q, s in enumerate(self.slots) if s.state == "settled"]

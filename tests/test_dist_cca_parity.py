@@ -50,7 +50,7 @@ SCRIPT = textwrap.dedent("""
     shards = cca_state_shardings(mesh, jax.eval_shape(lambda: st0))
     # the mapping: cell rows over 'data', cell columns over 'model'
     from jax.sharding import PartitionSpec as P
-    assert shards.vals.spec == P("data", "model", None, None)
+    assert shards.vals.spec == P("data", "model", None)
     assert shards.aq_n.spec == P("data", "model")
     assert shards.cycle.spec == P()
     sB = jax.device_put(st0, shards)

@@ -29,6 +29,7 @@ from repro.core.config import EngineConfig
 from repro.core.engine import StreamingEngine
 from repro.core.reference import (bfs_levels, cc_labels, sssp_dists,
                                   widest_caps)
+from repro.core.state import vals_index
 from repro.graph.streams import StreamSpec, hashed_pair_weights, make_stream
 from repro.mq.session import DEFAULT_SEEDS, MQSession, QuerySlot
 
@@ -64,7 +65,7 @@ def _seed_single(eng, app_name, source):
         r, c, s = rhizome_rcs(cfg, vids, ks)
         labels = np.broadcast_to(vids.astype(np.float32), r.shape)
         eng.state = eng.state._replace(
-            vals=eng.state.vals.at[r, c, s, 0].set(labels))
+            vals=eng.state.vals.at[vals_index(cfg, r, c, s)].set(labels))
     else:
         eng.seed(source, DEFAULT_SEEDS[app_name])
 

@@ -88,11 +88,34 @@ def test_device_loop_compiles_8x8(one_chip):
     assert compiled.memory_analysis().argument_size_in_bytes > 0
 
 
-def test_device_loop_fits_one_chip_at_paper_size(one_chip):
+@pytest.fixture(scope="module")
+def bfs_loop_paper(one_chip):
+    """The BFS device loop at ``chip_32x32_50k``, compiled once (~1 min)."""
     from repro.core.apps import BFS
-    mem = _compile_device_loop(_paper_cfg(), BFS, one_chip).memory_analysis()
+    return _compile_device_loop(_paper_cfg(), BFS, one_chip)
+
+
+def test_device_loop_fits_one_chip_at_paper_size(bfs_loop_paper):
+    mem = bfs_loop_paper.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < HBM_BYTES, used
+
+
+def test_bfs_loop_keeps_vals_unpadded(bfs_loop_paper):
+    """Single-query ``vals`` is ``[H,W,S]``: a size-1 value axis would sit
+    on the 128 lanes, and the loop would carry and rewrite a 128-fold
+    padded copy (~130 MB at this size) every cycle.  Besides the io
+    stream's relayout before the loop (``io_edges [IO, L, 3]`` with its
+    3-word record on the lanes, once per pass) the temporaries hold
+    under 32 MB."""
+    import re
+    cfg = _paper_cfg()
+    io_relayout = cfg.io_cells * cfg.io_stream_cap * 128 * 4
+    temp = bfs_loop_paper.memory_analysis().temp_size_in_bytes
+    assert temp - io_relayout < 32 * 2**20, temp
+    carries = re.findall(r"= \((\w+\[[\d,]*\])\S* .*? while\(",
+                         bfs_loop_paper.as_text())
+    assert carries and set(carries) == {"f32[32,32,244]"}, carries
 
 
 def test_mq_q4_device_loop_compiles_32x32(one_chip):
