@@ -32,29 +32,45 @@ def drive(run_batch, batches, seconds: float, clock,
           span=lambda name: contextlib.nullcontext(), tail=None) -> dict:
     """Offer ``batches`` to ``run_batch`` for a window of ``seconds``.
 
-    ``tail``, where given, is ``(s, context)``: ``context()`` is entered
-    before the first batch that starts with at most ``s`` seconds of the
-    window left, or before the stream's last batch if that comes first,
-    and left once the window's end has been read (the harness's profiler
-    trace of the window's tail).
+    ``tail``, where given, is ``(s, context)``: the harness's profiler
+    trace of whole batches at the window's end, which a trace keeps whole
+    only up to a bounded number of device events.  ``context()`` is
+    entered before the stream's last batch or before the first batch that
+    starts with at most ``max(s - L, L)`` seconds of the window left,
+    ``L`` the longest batch so far, whichever comes first.  It is left
+    once the window's end has been read, or at the batch boundary where
+    one more batch as long as ``L`` would take the traced batches past
+    ``s`` seconds; no batch starts after that once leaving it took the
+    window past its end.  So the tail holds at most ``s`` seconds of
+    batches, or one batch where ``L`` passes ``s / 2``, as far as no batch
+    outlasts the longest before it.
 
     Returns the window record: ``t0`` and ``end`` (the window's start and
     the end of its last batch, on ``clock``), ``batches``, one dict per
     batch offered: ``edges``, ``start``, ``done`` (``None`` where it
     failed), ``failed``, and the engine's ``result`` where it finished;
-    ``tail_from``, the index of the first batch inside ``tail`` (``None``
-    without one); ``error``, the failure's message."""
-    recs, error, tail_from = [], None, None
+    ``tail_from`` and ``tail_to``, the indices of the first batch inside
+    ``tail`` and of the first after it (``None`` without one); ``error``,
+    the failure's message."""
+    recs, error, tail_from, tail_to = [], None, None, None
     with contextlib.ExitStack() as stack:
         t0 = clock()
+        longest = 0.0
         for k, b in enumerate(batches):
             start = clock()
             if tail is not None and tail_from is None and (
-                    start - t0 >= seconds - tail[0]
+                    seconds - (start - t0) <= max(tail[0] - longest, longest)
                     or k == len(batches) - 1):
                 stack.enter_context(tail[1]())
                 tail_from = len(recs)
+                traced_from = start = clock()
+            elif tail_from is not None and tail_to is None and (
+                    start - traced_from + longest > tail[0]):
+                stack.close()
+                tail_to = len(recs)
                 start = clock()
+                if start - t0 >= seconds:
+                    break
             try:
                 with span("bench.run_increment"):
                     res = run_batch(b)
@@ -66,8 +82,11 @@ def drive(run_batch, batches, seconds: float, clock,
             done = clock()
             recs.append(dict(edges=len(b), start=start, done=done,
                              failed=False, result=res))
+            longest = max(longest, done - start)
             if done - t0 >= seconds:
                 break
         end = clock()
+    if tail_from is not None and tail_to is None:
+        tail_to = len(recs)
     return dict(t0=t0, end=end, batches=recs, tail_from=tail_from,
-                error=error)
+                tail_to=tail_to, error=error)

@@ -6,13 +6,17 @@ found by name from ``BENCHMARK.json``:
 
 - ``bench/configs/<config>.json``: the deployment (graph stream, machine,
   program preset, session kind, standing queries and their limits);
+- ``bench/streams/<kind>.py``: the generator of the stream kind that the
+  configuration's ``graph`` section names;
 - ``bench/traffic/<traffic>.json``: the mix, read by the generator module
   ``bench/drivers/<driver>.py`` that the file names;
 - ``bench/sessions/<session>.py``: how the configuration's queries are
   served;
 - ``bench/checks/<app>.py``: the plain reference of each query's app and
   the number compared;
-- ``bench/metrics/<metric>.py``: the reader of each metric.
+- ``bench/metrics/<metric>.py``: the reader of each metric; a per-layer
+  reader of a traced run finds the device time of every ``cca.*`` scope
+  of the engine's device loop by its name in ``RunView.trace``.
 """
 from __future__ import annotations
 
@@ -162,7 +166,7 @@ class RunView:
     cell: Cell
     window: dict             # drivers' window record
     setup_s: float
-    trace: dict | None       # trace.reduce() of the traced window
+    trace: dict | None       # reduce_trace() of the traced window
 
 
 def load_reader(name: str):
@@ -183,32 +187,62 @@ def read_metrics(metrics: list, view: RunView) -> dict:
     return out
 
 
-# seconds at the window's end that a traced run profiles: the device trace
-# holds every op of every machine cycle, some 0.6M events a second on a
-# v5e, and a longer one takes minutes to collect and read
-TRACE_SECONDS = 5.0
+# seconds of whole batches at the window's end that a traced run
+# profiles: the device trace holds every op of every machine cycle, some
+# 1.2M device events a second of the engine's loop on a v5e, and keeps
+# only its first ~7.3M (the first ~5.9 s of busy device), so a longer
+# tail would read low
+TRACE_SECONDS = 4.0
+# seconds between starting the profiler and the first traced batch: the
+# device's tracer starts collecting some time after start_trace returns
+# (once on a v5e it missed the first ~0.1 s of a loop that began 0.12 s
+# after), and an op it misses would under-read the tail
+TRACE_SETTLE_S = 2.0
 
 
 @contextlib.contextmanager
 def traced(box: list):
     """Profile the enclosed block, under the host span ``bench.traced``,
-    into a temporary directory; ``box[0]`` holds the trace's ProfileData
-    after the block."""
+    into a temporary directory, which ``box[0]`` names after the block:
+    :func:`read_trace` reads it once the window has closed."""
     import jax
-    from jax.profiler import ProfileData
     d = tempfile.mkdtemp(prefix="bench-trace-")
+    box[0] = d
+    jax.profiler.start_trace(d)
     try:
-        jax.profiler.start_trace(d)
-        try:
-            with span("bench.traced"):
-                yield
-        finally:
-            jax.profiler.stop_trace()
+        time.sleep(TRACE_SETTLE_S)
+        with span("bench.traced"):
+            yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def read_trace(d: str):
+    """The ProfileData of the trace that :func:`traced` wrote to ``d``
+    (``None`` where it wrote none), and ``d`` removed."""
+    from jax.profiler import ProfileData
+    try:
         pbs = sorted(pathlib.Path(d).rglob("*.xplane.pb"))
-        if pbs:
-            box[0] = ProfileData.from_file(str(pbs[-1]))
+        return ProfileData.from_file(str(pbs[-1])) if pbs else None
     finally:
         shutil.rmtree(d, ignore_errors=True)
+
+
+def reduce_trace(pd, op_names: dict) -> dict | None:
+    """The traced view that readers get: :func:`bench.trace.reduce`'s
+    numbers with :func:`bench.stages.reduce`'s (device time by every
+    ``cca.*`` scope, ``repro.*`` span time, idle by span) where the
+    device loop ran in the tail; ``None`` where the trace does not
+    reduce."""
+    from bench import stages, trace as trace_mod
+    t = time.perf_counter()
+    out = trace_mod.reduce(pd)
+    t_old = time.perf_counter() - t
+    if out is not None:
+        out.update(stages.reduce(pd, op_names=op_names) or {})
+    log(f"bench.trace.reduce {t_old:.3f}s, bench.stages.reduce "
+        f"{time.perf_counter() - t - t_old:.3f}s")
+    return out
 
 
 def span(name: str):
@@ -260,7 +294,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     and the window's batches (for the control readings)."""
     import jax
 
-    from bench import stream, trace as trace_mod
+    from bench import stages, stream, trace as trace_mod
     clock = time.perf_counter
     counter = CompileCounter()
     config, traffic = cell.config, cell.traffic
@@ -314,26 +348,51 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         f"{sum(r['result'].cycles for r in done)} machine cycles, "
         f"{window['end'] - window['t0']:.3f}s"
         + (f", error {window['error'][:400]}" if window["error"] else ""))
+    log("window batches [start s, wall s, machine cycles]: " + json.dumps(
+        [[round(r["start"] - window["t0"], 4),
+          round(r["done"] - r["start"], 4), r["result"].cycles]
+         for r in done]) + f"; traced from batch {window['tail_from']} to "
+        f"{window['tail_to']}")
 
     with span("bench.readback"):
         got = {q: np.asarray(sess.values(q))
                for q in range(len(queries))}
     peak = memory_peak_bytes()
+    # the device loop's arguments as shapes, for its HLO text below
+    loop = stages.loop_args(sess.eng) if box[0] is not None else None
     sess.close()
     del sess
     gc.collect()
-    reduced = None
+    reduced = op_names = None
     if box[0] is not None:
         t = clock()
-        reduced = trace_mod.reduce(box[0])
-        box[0] = None
-        log(f"trace reduced in {clock() - t:.3f}s: "
+        pd = read_trace(box[0])
+        t_read = clock() - t
+        op_names = stages.loop_op_names(*loop)
+        t_hlo = clock() - t - t_read
+        reduced = reduce_trace(pd, op_names) if pd is not None else None
+        del pd
+        log(f"trace read in {t_read:.3f}s; the device loop's HLO op names "
+            f"in {t_hlo:.3f}s (programs built, cache hits, misses: "
+            f"{counter.snapshot()}); trace reduced in "
+            f"{clock() - t - t_read - t_hlo:.3f}s: "
             + (json.dumps({k: v for k, v in reduced.items()
-                           if k.endswith("_ns") or k.startswith("n_")})
+                           if k.endswith(("_ns", "_share", "_cut",
+                                          "_unseen")) or k.startswith("n_")})
                if reduced else "no device programs in the traced tail"))
 
     if trace and reduced is None:
         problems.append("the trace of the window's tail could not be reduced")
+    elif trace and reduced["batches_unseen"]:
+        problems.append(f"the trace holds no device loop in "
+                        f"{reduced['batches_unseen']} of the traced batches: "
+                        f"it dropped the device's later events, and the tail "
+                        f"would read low")
+    elif trace and reduced["loops_cut"]:
+        problems.append(f"the trace lost the ops of {reduced['loops_cut']} "
+                        f"runs of the device loop in the tail (their outer "
+                        f"while missing or short): it began collecting late, "
+                        f"and the tail would read low")
     view = RunView(cell=cell, window=window, setup_s=setup_s, trace=reduced)
     declared = cell.per_layer if trace else cell.end_to_end
     metrics = read_metrics(declared, view)
@@ -346,7 +405,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"reference over {len(edges)} edges: {clock() - t:.3f}s")
     if keep is not None:
         keep.update(got=got, window=window, preload=preload,
-                    batches=batches, edges=edges, queries=queries)
+                    batches=batches, edges=edges, queries=queries,
+                    trace=reduced, op_names=op_names)
     correct = failed == 0 and all(c["value"] <= c["limit"]
                                   for c in compared.values())
     dev = dict(device or dict(platform=jax.devices()[0].platform,
@@ -358,7 +418,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if trace and reduced is not None:
         dev["busy_s"] = reduced["busy_ns"] / 1e9
         dev["window_s"] = reduced["window_ns"] / 1e9
-        out["breakdown"] = dict(device_ops=reduced["device_ops"],
-                                idle_gaps=reduced["idle_gaps"])
+        out["breakdown"] = dict(
+            device_ops=reduced["device_ops"], idle_gaps=reduced["idle_gaps"],
+            idle_by_span=reduced.get("idle_by_span", [])[:trace_mod.TOP])
     out["compared"] = compared
     return out

@@ -1,0 +1,6 @@
+"""Device microseconds of scope ``cca.quiescent`` per machine cycle."""
+from bench.stages import stage_us_per_cycle
+
+
+def read(view):
+    return stage_us_per_cycle(view, "cca.quiescent")

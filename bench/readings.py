@@ -33,7 +33,8 @@ def traced_batches(view) -> list:
     k = view.window.get("tail_from")
     if k is None:
         return []
-    return [r for r in view.window["batches"][k:] if r["done"] is not None]
+    return [r for r in view.window["batches"][k:view.window["tail_to"]]
+            if r["done"] is not None]
 
 
 def device_ms_per_cycle(view) -> float | None:

@@ -27,19 +27,29 @@ text (a v5e trace keeps an op's ``op_name`` in its event metadata, which
   longest first;
 - ``ops``: the longest leaf ops of the loop with their stage;
 - ``mixed_ns``: the time of the loop's ops that fuse ops of more than
-  one stage.
+  one stage;
+- ``scopes``: every ``cca.*`` scope that some op of the compiled loop
+  carries, as its own ``op_name`` or fused into another op, whether or
+  not an op of it roots time in ``stages``;
+- ``ops_lost_ns``: the stretches longer than ``OP_GAP_NS`` inside the
+  device loop's programs in which the trace holds no leaf op (the loop's
+  ops follow each other microseconds apart): a block of events the trace
+  dropped; ``loop_seen_share``, the share of the programs' time outside
+  them, and ``longest_op_gap_ns``.
 
-The per-layer numbers (:func:`stage_us_per_cycle`,
-:func:`host_ingest_ms_per_kedge`) read these keys from a
-:class:`bench.harness.RunView` whose ``trace`` holds them.
+The harness adds these keys to the traced view that readers get
+(:func:`bench.harness.reduce_trace`), with the op names of the device
+loop's HLO text (:func:`loop_op_names`), taken after the window.  The
+per-layer readers under ``bench/metrics/`` read a scope by its name with
+:func:`stage_us_per_cycle`, or the host's ingest with
+:func:`host_ingest_ms_per_kedge`.
 
 Run as a script, it makes one run of a cell through the harness, the
 same run as ``bench/run.py``'s, and prints one JSON line: the run's
-result, each batch's wall time in the window's traced tail (the last
-three batches of an untraced run), and, with
-``--trace 1``, the reduction above with the per-stage numbers, and the
-scopes that each of the loop's longest ops fuses, read from the
-compiled device loop's HLO::
+result, each batch's edges, wall time and machine cycles (of the
+window's traced tail in a traced run, of every batch in an untraced
+one), and, with ``--trace 1``, the stage split of the traced view, and
+the scopes that each of the loop's longest ops fuses::
 
   python3 bench/stages.py --workload <cell> --seed <n> --seconds <s> --trace 1
 """
@@ -49,22 +59,18 @@ import bisect
 import collections
 import re
 
+from bench.trace import opcode
+
 SCOPE_PREFIX = "cca."
 SPAN_PREFIXES = ("bench.", "repro.")
 UNATTRIBUTED = "unattributed"
 CONTAINERS = ("while", "conditional", "call")
-# the per-stage metrics and the scope each reads
-STAGE_METRICS = {
-    "hop_us_per_cycle.thru": "cca.hop",
-    "park_us_per_cycle.thru": "cca.park",
-    "staging_us_per_cycle.thru": "cca.staging",
-    "phase0_us_per_cycle.thru": "cca.phase0",
-    "io_us_per_cycle.thru": "cca.io",
-    "quiescence_us_per_cycle.thru": "cca.quiescent",
-}
+# a stretch inside the device loop with no op for this long is events the
+# trace dropped (a v5e trace drops a block of ~50 ms in about one tail in
+# four, keeping the program's own event and its outer while)
+OP_GAP_NS = 1e6
 TOP = 12
 
-_OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
@@ -73,14 +79,6 @@ def scope_of(op_name: str | None) -> str:
     comps = [c for c in (op_name or "").split("/")
              if c.startswith(SCOPE_PREFIX)]
     return comps[-1] if comps else UNATTRIBUTED
-
-
-def opcode(event_name: str) -> str | None:
-    """The HLO opcode of a TPU op event, which is named by its whole
-    instruction (``%while.7 = (s32[], ...) while(...), ...``)."""
-    head, _, rest = event_name.partition(" = ")
-    m = _OPCODE.search(rest) if rest else None
-    return m.group(1) if m else None
 
 
 def op_key(event_name: str) -> str:
@@ -114,6 +112,23 @@ def hlo_op_names(hlo_text: str) -> dict:
         fused.discard(UNATTRIBUTED)
         out[name] = (on, fused)
     return out
+
+
+def loop_args(eng) -> tuple:
+    """The arguments of ``eng``'s next call of the engine's device loop,
+    the state as shapes: what :func:`loop_op_names` compiles."""
+    import jax
+    state = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding), eng.state)
+    return eng.cfg, eng.app, state, eng.cfg.max_cycles
+
+
+def loop_op_names(cfg, app, state, limit) -> dict:
+    """:func:`hlo_op_names` of the engine's device loop compiled for these
+    arguments: the program that the window ran, found in the cache."""
+    from repro.core import engine
+    return hlo_op_names(engine._increment_device_loop.lower(
+        cfg, app, state, limit).compile().as_text())
 
 
 def _spans(pd) -> list:
@@ -153,6 +168,8 @@ def reduce(pd, op_names: dict | None = None, chip: int = 0) -> dict | None:
         return None
     starts = [s for s, _ in loops]
     by_op, scope, seen = collections.Counter(), {}, {}
+    # how far each program's leaf ops reach, on the line's time order
+    reach, op_gaps = [s for s, _ in loops], []
     ops_line = lines.get(trace_mod.OPS_LINE)
     for ev in (ops_line.events if ops_line is not None else ()):
         s = ev.start_ns
@@ -167,7 +184,13 @@ def reduce(pd, op_names: dict | None = None, chip: int = 0) -> dict | None:
                 scope[key] = scope_of(op_names.get(key, (None,))[0])
         key = seen[name]
         if key is not None:
-            by_op[key] += min(ev.end_ns, loops[k][1]) - s
+            end = min(ev.end_ns, loops[k][1])
+            by_op[key] += end - s
+            if s > reach[k]:
+                op_gaps.append(s - reach[k])
+            reach[k] = max(reach[k], end)
+    op_gaps += [e - r for (_, e), r in zip(loops, reach) if e > r]
+    lost = sum(g for g in op_gaps if g > OP_GAP_NS)
     stages = collections.Counter()
     for key, ns in by_op.items():
         stages[scope[key]] += ns
@@ -185,22 +208,31 @@ def reduce(pd, op_names: dict | None = None, chip: int = 0) -> dict | None:
         mixed_ns=sum(v for n, v in by_op.items()
                      if len(op_names.get(n, (None, ()))[1]) > 1),
         span_ns=dict(span_ns),
+        scopes=sorted(set().union(*(
+            fused | {scope_of(on)} for on, fused in op_names.values()))
+            - {UNATTRIBUTED}),
+        ops_lost_ns=lost, longest_op_gap_ns=max(op_gaps, default=0.0),
+        loop_seen_share=1.0 - lost / sum(e - s for s, e in loops),
         idle_by_span=[[n, v / 1e9] for n, v in idle.most_common()],
         ops=[[n, scope[n], v / 1e9] for n, v in by_op.most_common(TOP)])
 
 
-def stage_us_per_cycle(view, stage: str) -> float | None:
-    """Device microseconds of ``stage`` per machine cycle of the tail's
-    done batches (the base of ``device_ms_per_cycle.thru``); ``None``
-    where no op of the loop carries a scope (a program without them)."""
+def stage_us_per_cycle(view, scope: str) -> float | None:
+    """Device microseconds of the ops of ``scope`` per machine cycle of
+    the tail's done batches (the base of ``device_ms_per_cycle.thru``)
+    that the trace kept (their cycles times ``loop_seen_share``): 0 where
+    the compiled loop carries the scope only inside fusions that another
+    scope roots; ``None`` where no op of the loop carries it."""
     from bench.readings import traced_batches
-    if view.trace is None or not any(
-            k.startswith(SCOPE_PREFIX) for k in view.trace.get("stages", ())):
+    trace = view.trace
+    if trace is None or scope not in set(trace.get("stages", ())) | set(
+            trace.get("scopes", ())):
         return None
-    cycles = sum(r["result"].cycles for r in traced_batches(view))
+    cycles = sum(r["result"].cycles for r in traced_batches(view)) * trace.get(
+        "loop_seen_share", 1.0)
     if not cycles:
         return None
-    return view.trace["stages"].get(stage, 0.0) / 1e3 / cycles
+    return trace["stages"].get(scope, 0.0) / 1e3 / cycles
 
 
 def host_ingest_ms_per_kedge(view) -> float | None:
@@ -216,76 +248,30 @@ def host_ingest_ms_per_kedge(view) -> float | None:
     return view.trace["span_ns"]["repro.load_stream"] / 1e6 / (edges / 1e3)
 
 
-def metrics(view) -> dict:
-    """Every per-stage number of one traced run, by metric name."""
-    out = {name: stage_us_per_cycle(view, scope)
-           for name, scope in STAGE_METRICS.items()}
-    out["host_ingest_ms_per_kedge.thru"] = host_ingest_ms_per_kedge(view)
-    return {k: v for k, v in out.items() if v is not None}
-
-
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         device: dict | None = None) -> dict:
     """One run of ``cell`` through :func:`bench.harness.run_cell`, its
-    result with the tail's batch times and, where traced, the stage
-    split: the object that the script prints."""
-    import time
-
-    import jax
-
+    result with the batch times and, where traced, the stage split of
+    the traced view: the object that the script prints."""
     from bench import harness
-    from bench import trace as trace_mod
-    from repro.core import engine
-
-    # the device loop's argument shapes, for its HLO text after the run
-    loop, seen = engine._increment_device_loop, {}
-
-    def recording_loop(cfg, app, st, limit):
-        if "args" not in seen:
-            seen["args"] = (cfg, app, jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=x.sharding), st),
-                limit)
-        return loop(cfg, app, st, limit)
-
-    # the trace, kept here too before the harness drops it
-    box, reduce_trace = {}, trace_mod.reduce
-
-    def both(pd, chip=0):
-        box["pd"] = pd
-        return reduce_trace(pd, chip)
-
-    engine._increment_device_loop = recording_loop
-    trace_mod.reduce = both
     keep = {}
-    try:
-        out = harness.run_cell(cell, seed, seconds, trace, t_start,
-                               device=device, keep=keep)
-    finally:
-        engine._increment_device_loop = loop
-        trace_mod.reduce = reduce_trace
+    out = harness.run_cell(cell, seed, seconds, trace, t_start,
+                           device=device, keep=keep)
     window = keep["window"]
-    k = window["tail_from"]
-    out["tail_batches"] = [
+    lo, hi = window["tail_from"], window["tail_to"]
+    out["batches"] = [
         [r["edges"], r["done"] - r["start"], r["result"].cycles]
-        for r in window["batches"][k if k is not None else -3:]
-        if r["done"] is not None]
-    if "pd" in box:
-        hlo = hlo_op_names(loop.lower(*seen["args"]).compile().as_text())
-        t = time.perf_counter()
-        red = reduce(box.pop("pd"), op_names=hlo)
-        out["stages_reduce_s"] = time.perf_counter() - t
-        if red is not None:
-            view = harness.RunView(cell=cell, window=window, setup_s=0.0,
-                                   trace=red)
-            out["stage_metrics"] = metrics(view)
-            out["stages"] = {n: v / 1e9 for n, v in red["stages"].items()}
-            out["loop_ops_s"] = red["loop_ops_ns"] / 1e9
-            out["span_s"] = {n: v / 1e9 for n, v in red["span_ns"].items()}
-            out["idle_by_span"] = red["idle_by_span"]
-            out["ops"] = [op + [sorted(hlo.get(op[0], (None, set()))[1])]
-                          for op in red["ops"]]
-            out["mixed_s"] = red["mixed_ns"] / 1e9
+        for r in window["batches"][lo or 0:hi] if r["done"] is not None]
+    red, hlo = keep["trace"], keep["op_names"] or {}
+    if red is not None and "stages" in red:
+        out["stages"] = {n: v / 1e9 for n, v in red["stages"].items()}
+        out["loop_ops_s"] = red["loop_ops_ns"] / 1e9
+        out["loop_s"] = red["loop_ns"] / 1e9
+        out["span_s"] = {n: v / 1e9 for n, v in red["span_ns"].items()}
+        out["idle_by_span"] = red["idle_by_span"]
+        out["ops"] = [op + [sorted(hlo.get(op[0], (None, set()))[1])]
+                      for op in red["ops"]]
+        out["mixed_s"] = red["mixed_ns"] / 1e9
     return out
 
 

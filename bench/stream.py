@@ -1,91 +1,24 @@
-"""The benchmark's own GraphChallenge-style edge stream: a degree-corrected
-stochastic block model, streamed in edge-sampled increments.
+"""A configuration's edge stream, made by the stream kind that its
+``graph`` section names.
 
-The generator the GraphChallenge paper describes for its stochastic block
-partition data sets is a degree-corrected SBM: vertex degrees follow a
-truncated power law and blocks are of uneven size.  Here:
+Each kind is a module of its own, ``bench/streams/<kind>.py``, found by
+the name and giving ``increments(graph) -> list[int32 [m, 2]]``: the
+graph, its cut into increments and its arrival order, all drawn from the
+section's own ``seed``.  A new kind enters as a new file.  The weights
+are every kind's: :func:`make_stream` adds them here.
 
-- block shares are drawn from a symmetric Dirichlet(``block_alpha``) over
-  ``n_blocks`` blocks, and each vertex's block from those shares;
-- each vertex has an out- and an in-propensity, each drawn from the power
-  law ``x^-degree_exponent`` truncated to ``[degree_min, degree_max]``;
-- an edge proposal picks its source by out-propensity, then, with the
-  intra-block probability that ``p_in_over_p_out`` gives over
-  ``n_blocks`` blocks, a target in the source's block, else a target
-  anywhere, by in-propensity;
-- self loops and repeated (src, dst) pairs are dropped, the first
-  proposal of each pair kept, until ``n_edges`` unique directed edges.
-
-``edge_sampled_stream`` and ``hashed_pair_weights`` are copied from the
-program's ``graph/streams.py`` so that a change to the program cannot
-move the yardstick.
+``hashed_pair_weights`` is copied from the program's ``graph/streams.py``
+so that a change to the program cannot move the yardstick.
 """
 from __future__ import annotations
 
+import importlib.util
+import pathlib
+
 import numpy as np
 
+STREAMS = pathlib.Path(__file__).resolve().parent / "streams"
 ONE_BITS = np.float32(1.0).view(np.int32)
-
-
-def power_law(rng, n: int, exponent: float, lo: float,
-              hi: float) -> np.ndarray:
-    """``n`` draws of density ``x^-exponent`` on ``[lo, hi]`` (inverse
-    CDF)."""
-    a = 1.0 - exponent
-    u = rng.random(n)
-    return (lo ** a + u * (hi ** a - lo ** a)) ** (1.0 / a)
-
-
-def _pick(cum: np.ndarray, lo: np.ndarray, hi: np.ndarray, u: np.ndarray):
-    """Indices drawn by weight from the cumulative weights ``cum``, within
-    the cumulative range ``[lo, hi)`` of each draw."""
-    return np.minimum(np.searchsorted(cum, lo + u * (hi - lo), side="right"),
-                      len(cum) - 1)
-
-
-def dcsbm_edges(graph: dict, seed: int) -> np.ndarray:
-    """``n_edges`` unique directed edges of the configuration's
-    degree-corrected SBM, as int32 ``[n_edges, 2]`` rows in proposal
-    order."""
-    rng = np.random.default_rng(seed)
-    V, B, E = graph["n_vertices"], graph["n_blocks"], graph["n_edges"]
-    share = rng.dirichlet(np.full(B, float(graph["block_alpha"])))
-    block = rng.choice(B, size=V, p=share)
-    deg = (graph["degree_exponent"], graph["degree_min"], graph["degree_max"])
-    theta_out = power_law(rng, V, *deg)
-    theta_in = power_law(rng, V, *deg)
-    order = np.argsort(block, kind="stable")
-    cum_in = np.cumsum(theta_in[order])
-    cum_out = np.cumsum(theta_out)
-    starts = np.searchsorted(block[order], np.arange(B))
-    ends = np.searchsorted(block[order], np.arange(B), side="right")
-    base = np.concatenate([[0.0], cum_in])
-    r = float(graph["p_in_over_p_out"])
-    p_intra = r / (r + B - 1)
-    keys = np.zeros(0, np.int64)
-    while len(keys) < E:
-        k = min(4 * (E - len(keys)) + 1024, 4_000_000)
-        zero = np.zeros(k)
-        src = _pick(cum_out, zero, zero + cum_out[-1], rng.random(k))
-        b = block[src]
-        intra = rng.random(k) < p_intra
-        lo = np.where(intra, base[starts[b]], 0.0)
-        hi = np.where(intra, base[ends[b]], cum_in[-1])
-        dst = order[_pick(cum_in, lo, hi, rng.random(k))]
-        ok = src != dst
-        cand = np.concatenate([keys, (src[ok].astype(np.int64) << 32)
-                               | dst[ok].astype(np.int64)])
-        _, first = np.unique(cand, return_index=True)
-        keys = cand[np.sort(first)]
-    keys = keys[:E]
-    return np.stack([keys >> 32, keys & 0xFFFFFFFF], axis=1).astype(np.int32)
-
-
-def edge_sampled_stream(edges: np.ndarray, increments: int,
-                        seed: int) -> list[np.ndarray]:
-    """Random arrival order, equal-size increments (Table 1 'Edge')."""
-    perm = np.random.default_rng(seed + 1).permutation(len(edges))
-    return [edges[p] for p in np.array_split(perm, increments)]
 
 
 def hashed_pair_weights(e: np.ndarray) -> np.ndarray:
@@ -98,6 +31,18 @@ def hashed_pair_weights(e: np.ndarray) -> np.ndarray:
     return w.astype(np.float32).view(np.int32)
 
 
+def load_kind(kind: str):
+    """The module of stream kind ``kind``: ``bench/streams/<kind>.py``."""
+    path = STREAMS / f"{kind}.py"
+    if not path.is_file():
+        raise ValueError(f"unknown stream kind {kind!r}: no file {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench.streams.{kind.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def make_stream(graph: dict) -> list[np.ndarray]:
     """The increments of a configuration's ``graph`` section, int32
     ``[m, 3]`` rows of (src, dst, weight bits).
@@ -107,13 +52,8 @@ def make_stream(graph: dict) -> list[np.ndarray]:
     are drawn from the section's own ``seed``, the same in every run.  The
     engine's work depends on the arrival order edge by edge, so a run's
     ``--seed`` draws none of it."""
-    if graph["kind"] != "dcsbm" or graph["sampling"] != "edge":
-        raise ValueError(f"unsupported stream {graph['kind']}/"
-                         f"{graph['sampling']}")
-    g = int(graph["seed"])
-    incs = edge_sampled_stream(dcsbm_edges(graph, g), graph["increments"], g)
     out = []
-    for inc in incs:
+    for inc in load_kind(graph["kind"]).increments(graph):
         if graph["weights"] == "unit":
             w = np.full(len(inc), ONE_BITS, np.int32)
         elif graph["weights"] == "hashed_pair":

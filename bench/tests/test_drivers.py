@@ -91,6 +91,51 @@ def test_tail_opens_late_in_the_window_or_at_the_last_batch(n, entered, first):
     w = drv.drive(runner(clock, [2.0] * n), [np.zeros((10, 3))] * n, 5.0,
                   clock, tail=(2.0, tail))
     assert tail.at == [entered, w["end"]] and w["tail_from"] == first
+    assert w["tail_to"] == len(w["batches"])
+
+
+def test_tail_closes_before_a_batch_that_would_pass_its_seconds():
+    # 2 s batches, a 10 s window, a 3 s tail: opened at 108 with the
+    # longest batch's 2 s left; the traced batch ends early (109.5), and
+    # another 2 s batch would take the tail past 3 s: it is left there,
+    # and the window runs on untraced to its end
+    clock = FakeClock()
+    tail = Tail(clock)
+    service = [2.0] * 4 + [1.5, 2.0, 2.0]
+    w = drv.drive(runner(clock, service), [np.zeros((10, 3))] * 7, 10.0,
+                  clock, tail=(3.0, tail))
+    assert tail.at == [108.0, 109.5]
+    assert (w["tail_from"], w["tail_to"]) == (4, 5)
+    assert len(w["batches"]) == 6 and w["end"] == 111.5
+
+
+def test_no_batch_starts_once_leaving_the_tail_passed_the_windows_end():
+    # as above, but leaving the tail takes 75 s (the profiler writing its
+    # trace): the window has ended by then, and no batch follows
+    clock = FakeClock()
+    tail = Tail(clock)
+
+    @contextlib.contextmanager
+    def slow_exit():
+        with tail():
+            yield
+        clock.t += 75.0
+    service = [2.0] * 4 + [1.5, 2.0, 2.0]
+    w = drv.drive(runner(clock, service), [np.zeros((10, 3))] * 7, 10.0,
+                  clock, tail=(3.0, slow_exit))
+    assert tail.at == [108.0, 109.5]
+    assert (w["tail_from"], w["tail_to"]) == (4, 5)
+    assert len(w["batches"]) == 5 and w["end"] == 184.5
+
+
+def test_tail_of_short_batches_holds_its_seconds_of_them():
+    # 0.5 s batches, a 10 s window, a 3 s tail: opened with 2.5 s left
+    clock = FakeClock()
+    tail = Tail(clock)
+    w = drv.drive(runner(clock, [0.5] * 30), [np.zeros((10, 3))] * 30, 10.0,
+                  clock, tail=(3.0, tail))
+    assert tail.at == [107.5, 110.0]
+    assert (w["tail_from"], w["tail_to"]) == (15, 20)
 
 
 @pytest.mark.parametrize("at", [0, 2])

@@ -10,7 +10,7 @@ import types
 
 import pytest
 
-from bench import stages, trace
+from bench import harness, stages, trace
 
 DATA = pathlib.Path(__file__).parent / "data"
 # the unscoped extract that test_trace.py reads
@@ -25,6 +25,24 @@ CHIP_TRACE = DATA / "tiny_bfs_v5e.textproto"
 SCOPED_TRACE = DATA / "tiny_bfs_v5e_scoped.textproto"
 SCOPED_NAMES = DATA / "tiny_bfs_v5e_scoped.op_names.json"
 LOOP = "jit(_increment_device_loop)/while/body/while/body"
+# the per-layer metrics of stages and host ingest, and the scope of each
+STAGE_METRICS = {
+    "hop_us_per_cycle.thru": "cca.hop",
+    "park_us_per_cycle.thru": "cca.park",
+    "staging_us_per_cycle.thru": "cca.staging",
+    "phase0_us_per_cycle.thru": "cca.phase0",
+    "io_us_per_cycle.thru": "cca.io",
+    "quiescence_us_per_cycle.thru": "cca.quiescent",
+}
+HOST_INGEST = "host_ingest_ms_per_kedge.thru"
+
+
+def read_stage_metrics(view) -> dict:
+    """What the readers under ``bench/metrics/`` of the seven metrics read
+    from ``view``, leaving out those that read nothing."""
+    out = {n: harness.load_reader(n)(view)
+           for n in [*STAGE_METRICS, HOST_INGEST]}
+    return {k: v for k, v in out.items() if v is not None}
 
 
 HAND_MADE = """
@@ -139,19 +157,78 @@ def test_fusions_of_two_stages_count_under_their_root_and_as_mixed():
 def _view(red, cycles, edges):
     batches = [dict(edges=edges, done=1.0, result=types.SimpleNamespace(
         cycles=cycles))]
-    return types.SimpleNamespace(trace=red, window=dict(batches=batches,
-                                                        tail_from=0))
+    return types.SimpleNamespace(trace=red, window=dict(
+        batches=batches, tail_from=0, tail_to=1))
 
 
 def test_stage_metrics_read_per_machine_cycle_and_per_kedge():
-    m = stages.metrics(_view(stages.reduce(_hand_made(), op_names=NAMES),
-                             cycles=10, edges=2000))
+    m = read_stage_metrics(_view(stages.reduce(_hand_made(), op_names=NAMES),
+                                 cycles=10, edges=2000))
     assert m["hop_us_per_cycle.thru"] == pytest.approx(0.3)
     assert m["quiescence_us_per_cycle.thru"] == pytest.approx(0.05)
-    assert m["io_us_per_cycle.thru"] == 0.0
     assert m["host_ingest_ms_per_kedge.thru"] == pytest.approx(3e-4)
-    assert len(m) == 7
-    assert stages.metrics(_view(None, 10, 2000)) == {}
+    # no op of the hand-made loop is of cca.io: a scope that the tail does
+    # not hold reads nothing, not 0
+    assert "io_us_per_cycle.thru" not in m and len(m) == 6
+    assert read_stage_metrics(_view(None, 10, 2000)) == {}
+
+
+@pytest.mark.parametrize("fused, want", [
+    # cca.io's ops all fuse into hop_fusion.1, which cca.hop roots: the
+    # loop holds the scope and its ops root no time, so it reads 0
+    ({"cca.hop", "cca.io"}, 0.0),
+    # no op of the loop carries cca.io: nothing to read (a misspelled or
+    # removed scope fails the run)
+    ({"cca.hop"}, None),
+])
+def test_a_scope_that_roots_no_op_reads_0_only_where_the_loop_holds_it(
+        fused, want):
+    names = dict(NAMES, **{"hop_fusion.1": (f"{LOOP}/cca.hop/add", fused)})
+    red = stages.reduce(_hand_made(), op_names=names)
+    assert "cca.io" not in red["stages"]
+    assert ("cca.io" in red["scopes"]) == (want is not None)
+    view = _view(red, cycles=10, edges=2000)
+    assert stages.stage_us_per_cycle(view, "cca.io") == want
+    assert harness.load_reader("io_us_per_cycle.thru")(view) == want
+    assert stages.stage_us_per_cycle(view, "cca.hop") == pytest.approx(0.3)
+
+
+# one 10 ms run of the loop whose ops stop for 4 ms in the middle: a block
+# of events the trace dropped
+DROPPED = """
+planes { id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000000 } }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 0
+    events { metadata_id: 3 offset_ps: 10000000 duration_ps: 9980000000 }
+    events { metadata_id: 4 offset_ps: 100000000 duration_ps: 1900000000 }
+    events { metadata_id: 5 offset_ps: 6000000000 duration_ps: 3900000000 } }
+  event_metadata { key: 1 value { id: 1 name: "jit__increment_device_loop(7)" } }
+  event_metadata { key: 3 value { id: 3 name: "%while.7 = (s32[]{:T(128)}) while((s32[]{:T(128)}) %tuple.1), condition=%cond.1, body=%body.1" } }
+  event_metadata { key: 4 value { id: 4 name: "%hop_fusion.1 = s32[8]{0:T(128)S(1)} fusion(s32[8]{0:T(128)} %p.1), kind=kLoop, calls=%fused_computation.1" } }
+  event_metadata { key: 5 value { id: 5 name: "%park_fusion.2 = s32[8]{0:T(128)} fusion(s32[8]{0:T(128)} %p.2), kind=kLoop, calls=%fused_computation.2" } }
+}
+planes { id: 2 name: "/host:CPU"
+  lines { id: 3 name: "python" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "bench.traced" } } }
+"""
+
+
+def test_a_block_of_dropped_ops_leaves_its_cycles_out_of_the_stages():
+    from jax.profiler import ProfileData
+    r = stages.reduce(ProfileData.from_text_proto(DROPPED), op_names=NAMES)
+    # 2..6 ms holds no op: 40% of the loop's time; the 0.1 ms before the
+    # first op and after the last are not a drop
+    assert r["ops_lost_ns"] == 4e6 and r["longest_op_gap_ns"] == 4e6
+    assert r["loop_seen_share"] == pytest.approx(0.6)
+    view = _view(r, cycles=10, edges=2000)
+    # 1.9 ms of hop over the 6 of 10 cycles the trace kept
+    assert stages.stage_us_per_cycle(view, "cca.hop") == pytest.approx(
+        1900 / 6)
+    full = stages.reduce(_hand_made(), op_names=NAMES)
+    assert full["ops_lost_ns"] == 0 and full["loop_seen_share"] == 1.0
+    assert full["longest_op_gap_ns"] == 2200
 
 
 def test_the_unscoped_extract_reduces_with_every_op_unattributed():
@@ -163,7 +240,7 @@ def test_the_unscoped_extract_reduces_with_every_op_unattributed():
     old = trace.reduce(pd)
     assert r["idle_by_span"] == old["idle_gaps"]
     # a program without scopes or spans: the per-stage numbers read nothing
-    assert stages.metrics(_view(r, cycles=10, edges=1000)) == {}
+    assert read_stage_metrics(_view(r, cycles=10, edges=1000)) == {}
 
 
 def test_opcode_reads_the_instruction_not_its_layouts():
@@ -213,7 +290,7 @@ def test_scoped_chip_extract_reduces_to_every_stage_and_repro_spans():
              for k, v in json.loads(SCOPED_NAMES.read_text()).items()}
     bare, r = stages.reduce(pd), stages.reduce(pd, op_names=names)
     assert set(bare["stages"]) == {stages.UNATTRIBUTED}
-    assert set(stages.STAGE_METRICS.values()) <= set(r["stages"])
+    assert set(STAGE_METRICS.values()) <= set(r["stages"])
     assert r["loop_ops_ns"] == bare["loop_ops_ns"] > 0
     # every idle gap lies under a repro.* span; the old reduction names
     # them all bench.run_increment, and both add up to the same idle time

@@ -3,11 +3,12 @@ read.
 
 The harness records the tail of the window under ``jax.profiler`` with
 its own host spans (``jax.profiler.TraceAnnotation``): ``bench.traced``
-around the whole traced tail and, inside it, ``bench.run_increment`` and
-``bench.mq_fold``.  The reduction takes from
-the trace's device plane of one chip (``/device:TPU:<n>``) the executions
-of whole XLA programs, and from the host plane those spans, all on the
-profiler's one clock:
+around the whole traced tail and, inside it, ``bench.run_increment``
+around each batch (the program's own ``repro.*`` spans, which
+:func:`bench.stages.reduce` reads, lie inside that).  The reduction takes
+from the trace's device plane of one chip (``/device:TPU:<n>``) the
+executions of whole XLA programs, and from the host plane those spans,
+all on the profiler's one clock:
 
 - ``busy_ns``: the union of the device's program intervals inside the
   traced tail;
@@ -18,7 +19,17 @@ profiler's one clock:
   inside a loop counts within the loop's time too;
 - ``idle_gaps``: the device's idle time inside the traced tail, by the
   innermost harness span open at each gap's midpoint (``bench.traced``
-  alone: the harness's own loop), longest first.
+  alone: the harness's own loop), longest first;
+- ``batches_unseen``: the traced batches (``bench.run_increment`` spans)
+  in which the trace holds no run of the device loop.  A trace keeps a
+  bounded number of device events and drops every later one: a batch
+  past that point shows no device work, and the numbers above under-read;
+- ``loops_cut``: the runs of the device loop inside the traced tail whose
+  longest ``while`` op on the ops line is missing or falls short of the
+  program's own event by more than ``CUT_MARGIN`` of it (at least
+  ``CUT_FLOOR_NS``): a trace that began collecting after the loop had
+  started keeps the program but drops its first ops, and its ops would
+  under-read.
 """
 from __future__ import annotations
 
@@ -30,8 +41,15 @@ MODULE_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 DEVICE_LOOP = "_increment_device_loop"
 WINDOW_SPAN = "bench.traced"
+BATCH_SPAN = "bench.run_increment"
 SPAN_PREFIX = "bench."
 TOP = 10
+# the device loop's outer ``while`` spans its program but for the copies
+# before and after it (under 0.2% of a loop on a v5e)
+CUT_MARGIN = 0.01
+CUT_FLOOR_NS = 1e6
+
+_OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
 
 
 def load(path: str):
@@ -44,6 +62,29 @@ def load(path: str):
     if raw[:2] == b"\x1f\x8b":
         raw = gzip.decompress(raw)
     return ProfileData.from_serialized_xspace(raw)
+
+
+def opcode(event_name: str) -> str | None:
+    """The HLO opcode of a TPU op event, which is named by its whole
+    instruction (``%while.7 = (s32[], ...) while(...), ...``)."""
+    head, _, rest = event_name.partition(" = ")
+    m = _OPCODE.search(rest) if rest else None
+    return m.group(1) if m else None
+
+
+def loops_cut(loops, ops) -> int:
+    """How many of the device loop's program events ``loops`` (start, end)
+    hold no ``while`` op event of ``ops`` as long as the program, less
+    the margin."""
+    whiles = sorted((s, e) for s, e, name in ops
+                    if " while(" in name and opcode(name) == "while")
+    cut = 0
+    for s, e in loops:
+        longest = max((we - ws for ws, we in whiles
+                       if s <= ws and we <= e), default=0.0)
+        cut += not longest or longest < (e - s) - max(
+            CUT_MARGIN * (e - s), CUT_FLOOR_NS)
+    return cut
 
 
 def _events(line):
@@ -119,8 +160,15 @@ def reduce(pd, chip: int = 0) -> dict | None:
     by_span = collections.Counter()
     for s, e in gaps:
         by_span[_innermost(inner, (s + e) / 2)] += e - s
+    loops = [(s, e) for s, e, name in modules if DEVICE_LOOP in name]
+    unseen = sum(not any(bs <= s and e <= be for s, e in loops)
+                 for bs, be, name in inner
+                 if name == BATCH_SPAN and lo <= bs and be <= hi)
+    cut = loops_cut([(s, e) for s, e in loops if lo <= s and e <= hi],
+                    lines.get(OPS_LINE, []))
     return dict(
         window_ns=hi - lo, busy_ns=busy, loop_ns=loop,
         n_modules=len(modules), n_ops=len(lines.get(OPS_LINE, [])),
+        batches_unseen=unseen, loops_cut=cut,
         device_ops=[[n, v / 1e9] for n, v in by_op.most_common(TOP)],
         idle_gaps=[[n, v / 1e9] for n, v in by_span.most_common(TOP)])
