@@ -19,6 +19,10 @@ def cca_shapes():
         # the paper's chip: 32x32 CCs, GraphChallenge 50K-vertex stream
         shape("chip_32x32_50k", "cca_stream", height=32, width=32,
               n_vertices=50_000, stream_edges=102_000),
+        # the same chip under Graph500's SCALE-16 R-MAT stream: 2^16
+        # vertices, 2^20 edges in 10 increments of 104,858 at most
+        shape("chip_32x32_rmat16", "cca_stream", height=32, width=32,
+              n_vertices=65_536, stream_edges=104_858),
         # pod-scale grids (one 32x32 tile of cells per device on 16x16 mesh)
         shape("chip_512x512_1m", "cca_stream", height=512, width=512,
               n_vertices=1_000_000, stream_edges=1_000_000),

@@ -50,6 +50,13 @@ class EngineConfig:
     # --- allocation policy (paper Fig. 5) ---
     allocator: str = "vicinity"   # "vicinity" (<=2 hops) | "random"
     vicinity_hops: int = 2
+    tail_insert: bool = False     # each root keeps its ghost chain's last
+                                  # node and fill count, sends an insert it
+                                  # has no room for straight there, and
+                                  # allocates the next ghost itself, linked
+                                  # behind the old tail (DESIGN §4.6);
+                                  # False = the classic walk down the chain
+                                  # (Listing 6), bit-exact and free
 
     # --- app ---
     n_vals: int = 1               # per-slot application values (BFS: level)
@@ -218,3 +225,10 @@ class EngineConfig:
                 f"futq_cap={self.futq_cap} exceeds the local-emission " \
                 f"reserve {self.aq_reserve}; shrink futq_cap or raise " \
                 "edge_cap/rhizome_cap"
+        if self.tail_insert:
+            # a new tail receives the inserts deferred on its root's
+            # future queue; they must fit it, or the tail would grow a
+            # ghost of its own behind the root's back (DESIGN §4.6)
+            assert self.futq_cap <= self.edge_cap, \
+                f"tail_insert needs futq_cap <= edge_cap, got " \
+                f"{self.futq_cap} > {self.edge_cap}"

@@ -46,9 +46,9 @@ from repro.core.config import EngineConfig
 from repro.core.exec_stage import phase0_stage, staging_stage
 from repro.core.ingest import io_stage, load_stream
 from repro.core.routing import hop_stage, park_stage
-from repro.core.state import (TM_HOP, TM_HW_AQ, TM_L_OCC, MachineState,
-                              init_state, root_addr, self_cell_grid,
-                              vals_index)
+from repro.core.state import (RZ_FWD, RZ_LINK, TM_HOP, TM_HW_AQ, TM_L_OCC,
+                              MachineState, init_state, root_addr,
+                              self_cell_grid, vals_index)
 from repro.obs import frames as obs_frames
 from repro.obs import spans as obs_spans
 
@@ -231,7 +231,8 @@ def _increment_device_loop(cfg: EngineConfig, app: DiffusionApp,
 
     Host<->device traffic per pass is exactly one donated state in and a
     handful of scalars out — no per-chunk ``int(stat_exec)`` syncs, no
-    per-cycle stats transfer.  Each chunk either runs
+    per-cycle stats transfer (the record ends with the rhizome counters,
+    ``stat_rhz``, where ``cfg.rhizome_cap > 1``).  Each chunk either runs
     :func:`run_to_quiescence_while` capped at ``cfg.chunk`` cycles
     (backend="jnp") or one fused Pallas megakernel launch of
     ``cfg.chunk`` cycles (backend="pallas"); both leave the state frozen
@@ -279,8 +280,11 @@ def _increment_device_loop(cfg: EngineConfig, app: DiffusionApp,
         ring0 = None  # empty pytree: rides the carry at zero cost
     st, _, noprog, ring = jax.lax.while_loop(
         cond, body, (st, st.stat_exec + st.stat_hops, jnp.int32(0), ring0))
-    return st, (st.cycle - start, quiescent(st), noprog, st.stat_hops,
-                st.stat_exec, st.stat_stall, st.stat_allocs), ring
+    out = (st.cycle - start, quiescent(st), noprog, st.stat_hops,
+           st.stat_exec, st.stat_stall, st.stat_allocs)
+    if cfg.rhizome_cap > 1:
+        out += (st.stat_rhz,)
+    return st, out, ring
 
 
 PALLAS_ON_TPU = (
@@ -302,6 +306,10 @@ class IncrementResult:
     execs: int
     stalls: int
     allocs: int
+    # rhizome protocol (DESIGN §4.5; 0 at rhizome_cap == 1): link
+    # requests emitted (OP_LINK_RHIZOME) and OP_RHIZOME_FWD actions run
+    rlinks: int = 0
+    rfwds: int = 0
     # telemetry frame log (``cfg.telemetry=True`` only, else None): the
     # last ``cfg.frame_ring`` per-chunk frames of each spill pass, read
     # back as one batched transfer per pass (DESIGN §8)
@@ -322,7 +330,8 @@ class StreamingEngine:
         self.state = init_state(cfg, init_vals=self.app.init_val,
                                 fwd_init=self.app.fwd_neutral)
         self.total_cycles = 0
-        self.totals = dict(hops=0, execs=0, stalls=0, allocs=0)
+        self.totals = dict(hops=0, execs=0, stalls=0, allocs=0, rlinks=0,
+                           rfwds=0)
         # resilience bookkeeping (DESIGN §9)
         self.stream_pos = 0        # increments completed == checkpoint step
         self.recovery_log = []     # one dict per livelock recovery attempt
@@ -450,9 +459,7 @@ class StreamingEngine:
             raise RuntimeError(self._spill_msg(limit, spill))
         if cfg.faults is not None:
             cycles = self._repair_rounds(limit, cycles, rings)
-            counters = tuple(int(x) for x in jax.device_get((
-                self.state.stat_hops, self.state.stat_exec,
-                self.state.stat_stall, self.state.stat_allocs)))
+            counters = self._counters()
             frames = (obs_frames.FrameLog.from_rings(rings)
                       if rings else None)
         if cfg.ingest_guard:
@@ -460,8 +467,19 @@ class StreamingEngine:
             # increment's action-queue hi-water marks
             self._update_ingest_budget()
         return self._finish_increment(
-            cycles, *counters,
+            cycles, counters,
             np.zeros(0, np.int32), np.zeros(0, np.int32), frames)
+
+    def _counters(self) -> tuple:
+        """The increment's counters as ``_finish_increment`` takes them:
+        (hops, execs, stalls, allocs, rlinks, rfwds), read from the
+        state."""
+        st = self.state
+        rhz = (st.stat_rhz[RZ_LINK], st.stat_rhz[RZ_FWD]) \
+            if self.cfg.rhizome_cap > 1 else (0, 0)
+        return tuple(int(x) for x in jax.device_get((
+            st.stat_hops, st.stat_exec, st.stat_stall, st.stat_allocs,
+            *rhz)))
 
     def _reset_counters(self):
         """Zero the per-increment counters before the device loop."""
@@ -470,6 +488,9 @@ class StreamingEngine:
                                          stat_exec=jnp.int32(0),
                                          stat_stall=jnp.int32(0),
                                          stat_allocs=jnp.int32(0))
+        if cfg.rhizome_cap > 1:
+            self.state = self.state._replace(
+                stat_rhz=jnp.zeros_like(self.state.stat_rhz))
         if cfg.qbatch > 1:
             # per-query relax counters reset per increment so the mq
             # session layer reads them as this-increment activity (§10);
@@ -492,8 +513,9 @@ class StreamingEngine:
     def _device_passes(self, cfg, spill, limit, rings, cycles=0):
         """Sync-free device passes until quiescence with the spill fully
         drained, or until the cycle/livelock budget trips.  Returns
-        ``(cycles, quiescent, noprog, (hops, execs, stalls, allocs),
-        spill)`` — counters are the increment-cumulative stat scalars.
+        ``(cycles, quiescent, noprog, (hops, execs, stalls, allocs,
+        rlinks, rfwds), spill)`` — counters are the increment-cumulative
+        stat scalars.
         Each pass runs under the host spans ``repro.dispatch`` (the
         device loop's call) and ``repro.wait`` (its readback, where the
         host waits for the device)."""
@@ -505,8 +527,9 @@ class StreamingEngine:
             # and the frame ring come back together
             with obs_spans.span("repro.wait"):
                 out, ring = jax.device_get((out, ring))
-            ran, q, noprog, hops, execs, stalls, allocs = \
-                (int(x) for x in out)
+            ran, q, noprog, *counters = (int(x) for x in out[:7])
+            rhz = out[7] if cfg.rhizome_cap > 1 else (0, 0)
+            counters = (*counters, int(rhz[RZ_LINK]), int(rhz[RZ_FWD]))
             if ring is not None:
                 rings.append(ring)
             cycles += ran
@@ -520,7 +543,7 @@ class StreamingEngine:
                                                 limit=self._ingest_limit())
                 continue
             break
-        return cycles, q, noprog, (hops, execs, stalls, allocs), spill
+        return cycles, q, noprog, counters, spill
 
     def _run_increment_traced(self, spill, limit) -> IncrementResult:
         """Chunked host loop with per-cycle activity traces (the original
@@ -572,8 +595,7 @@ class StreamingEngine:
         frames = (obs_frames.FrameLog.from_rings([jax.device_get(ring)])
                   if ring is not None else None)
         return self._finish_increment(
-            cycles, int(self.state.stat_hops), int(self.state.stat_exec),
-            int(self.state.stat_stall), int(self.state.stat_allocs),
+            cycles, self._counters(),
             np.concatenate(act) if act else np.zeros(0, np.int32),
             np.concatenate(flt) if flt else np.zeros(0, np.int32), frames)
 
@@ -738,16 +760,17 @@ class StreamingEngine:
                 "edges not yet ingested; raise max_cycles or io_stream_cap "
                 "(DESIGN.md §4.2).")
 
-    def _finish_increment(self, cycles, hops, execs, stalls, allocs,
-                          act, flt, frames=None) -> IncrementResult:
+    def _finish_increment(self, cycles, counters, act, flt,
+                          frames=None) -> IncrementResult:
+        """``counters``: (hops, execs, stalls, allocs, rlinks, rfwds)."""
         self.total_cycles += cycles
-        for k, v in zip(("hops", "execs", "stalls", "allocs"),
-                        (hops, execs, stalls, allocs)):
+        named = dict(zip(("hops", "execs", "stalls", "allocs", "rlinks",
+                          "rfwds"), counters))
+        for k, v in named.items():
             self.totals[k] += v
         return IncrementResult(
             cycles=cycles, active_per_cycle=act, in_flight_per_cycle=flt,
-            hops=hops, execs=execs, stalls=stalls, allocs=allocs,
-            frames=frames)
+            frames=frames, **named)
 
     # -- read back application values from the vertex objects --
     def values(self, n: int | None = None, val_idx: int = 0,

@@ -45,9 +45,9 @@ from repro.core.msg import (MSG_WORDS, OP_ALLOC, OP_APP, OP_INSERT_EDGE,
                             make_qmsg, msg_qvals, msg_seal, pad_msg,
                             qsel_mask, seal_msg)
 from repro.core.routing import deliver, msg_lane, yx_target_buffer
-from repro.core.state import (G_NULL, G_PENDING, G_SET, MachineState,
-                              TM_ALLOC, TM_BCAST, TM_EXEC, TM_PARK, TM_STAGE,
-                              TM_STALL)
+from repro.core.state import (G_NULL, G_PENDING, G_SET, RZ_FWD, RZ_LINK,
+                              MachineState, TM_ALLOC, TM_BCAST, TM_EXEC,
+                              TM_PARK, TM_STAGE, TM_STALL, tail_pending)
 
 
 def _oh(idx, n, mask=None):
@@ -161,15 +161,25 @@ def staging_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
             f2i(neutral_vec(app.init_val))[1:], (H, W, QB - 1))
         sf_fq_app = make_qmsg(OP_APP, ga,
                               jnp.concatenate([fq_e[..., 1:2], qn], axis=-1))
+    # (with tail_insert a root's deferred inserts go to its chain's tail,
+    # which phase 0 has set to the ghost this set-future names)
+    ins_tgt = (jnp.where(slot < cfg.primary_slots, sel(st.gtail, slot), ga)
+               if cfg.tail_insert else ga)
     sf_fq_msg = jnp.where(
         sf_is_ins[..., None],
-        wm(make_msg(OP_INSERT_EDGE, ga, fq_e[..., 1], fq_e[..., 2])),
+        wm(make_msg(OP_INSERT_EDGE, ins_tgt, fq_e[..., 1], fq_e[..., 2])),
         sf_fq_app)
     sf_from_fq = is_sf & (fqn_cur > 0)
     sf_from_fwd = is_sf & (fqn_cur == 0)   # the coalesced forward
     fwd_here = sel(st.fwd_val, slot)
     sf_msg = jnp.where(sf_from_fq[..., None], sf_fq_msg,
                        qmsg(OP_APP, ga, fwd_here))
+    if cfg.tail_insert:
+        # no forward pending after the drains: the root's link of its new
+        # tail behind the old, which phase 0 left in cout (DESIGN §4.6)
+        fwdp = sel(st.fwd_pending, slot)
+        sf_msg = jnp.where((sf_from_fwd & ~fwdp)[..., None], st.cout, sf_msg)
+        sf_from_fwd = sf_from_fwd & fwdp
 
     # ---- rf activation drain: re-inject a deferred insert at this (now
     #      active) rhizome root — it is local by construction ----
@@ -328,6 +338,12 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
     # an insert reaching one before its link-ack must defer (DESIGN §4.5)
     in_sec = (slot >= cfg.root_slots) & (slot < cfg.primary_slots)
     inactive = in_sec & ~on_s
+    ti = cfg.tail_insert
+    if ti:
+        # a root's view of its ghost chain's last node (DESIGN §4.6)
+        is_root = slot < cfg.primary_slots
+        tc = sel(st.tcnt, slot)
+        gt = sel(st.gtail, slot)
 
     # ---------------- INSERT-EDGE paths (Listing 6) ----------------
     room = ne < E
@@ -339,16 +355,27 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
     # later ones just defer on the same future queue (Fig. 4 machinery)
     p_rlink = is_ins & inactive & (rs == G_NULL)
     p_rdef = is_ins & inactive & (rs == G_PENDING)
+    defers = p_defer | p_rlink | p_rdef
+    if ti:
+        # a full root sends the insert to its tail while the tail has
+        # room, asks for a new tail once it is full, and defers on its
+        # future queue while that request is out
+        p_tnull = p_fwd & is_root & (tc == E)
+        p_tdef = p_fwd & is_root & (tc > E)
+        p_fwd = p_fwd & ~(p_tnull | p_tdef)
+        defers = defers | p_tnull | p_tdef
 
     # the only infeasible phase-0: deferred insert with a full future
     # queue.  The head is ROTATED to the queue tail (costs this cell's
     # cycle) — the paper's runtime "schedules other tasks", so a blocked
     # action never wedges the FIFO in front of the set-future it waits on.
-    feasible = ~((p_defer | p_rlink | p_rdef) & (fqn >= FQ))
+    feasible = ~(defers & (fqn >= FQ))
     pop = has & feasible
     rotate = has & ~feasible
     p_room &= pop; p_fwd &= pop; p_defer &= pop; p_null &= pop
     p_rlink &= pop; p_rdef &= pop
+    if ti:
+        p_tnull &= pop; p_tdef &= pop
     is_app &= pop; is_alc &= pop; is_sf &= pop; is_rf &= pop; is_lr &= pop
     if is_rp is not None:
         is_rp &= pop
@@ -373,11 +400,14 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
 
     # -- fwd: recursively propagate the insert to the ghost (Listing 6 l.29)
     ga_cur = sel(st.gaddr, slot)
-    fwd_out = wm(make_msg(OP_INSERT_EDGE, ga_cur, a0, a1))
+    fwd_tgt = jnp.where(is_root, gt, ga_cur) if ti else ga_cur
+    fwd_out = wm(make_msg(OP_INSERT_EDGE, fwd_tgt, a0, a1))
 
     # -- defer: enqueue the insert on the pending future (Fig. 4 step 3)
     # (rhizome-pending slots reuse the same queue: Fig. 4 step 3 again)
     push_mask = p_defer | p_null | p_rlink | p_rdef
+    if ti:
+        push_mask = push_mask | p_tnull | p_tdef
     fqh = sel(st.fq_head, slot)
     tailq = (fqh + fqn) % FQ
     ohq = (_oh(slot, S, push_mask)[..., None]
@@ -390,7 +420,10 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
     # -- null: future -> pending, send allocate with continuation (Fig. 3)
     gstate = put(st.gstate, slot, G_PENDING, p_null)
     tgt_cell = choose_alloc_cell(cfg, rows, cols, st.arot)
-    arot = st.arot + p_null.astype(jnp.int32)
+    # (a root's request for a new tail is the same allocate, answered by
+    # the same set-future)
+    asks = (p_null | p_tnull) if ti else p_null
+    arot = st.arot + asks.astype(jnp.int32)
     null_out = make_msg(OP_ALLOC, tgt_cell * S, dst, f2i(vals_s[..., 0]))
     if QB > 1:
         # OP_ALLOC carries the requester's full value vector: word 3 is
@@ -490,23 +523,47 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
                                       axis=-1)
 
     # ---------------- SET-FUTURE (continuation return, Fig. 3/4) ----------
-    gaddr = put(gaddr0, slot, a0, is_sf)
-    gstate = put(gstate, slot, G_SET, is_sf)
     sf_T = jnp.where(is_sf,
                      fqn + sel(st.fwd_pending, slot).astype(jnp.int32), 0)
+    if ti:
+        # at a root: the answer to a request for a new tail (the chain
+        # exists, gstate is set) keeps gaddr and is linked behind the old
+        # tail by one more emission, cout; the deferred inserts drain to
+        # the new tail, which then holds them (DESIGN §4.6)
+        sf_root = is_sf & is_root
+        sf_link = sf_root & (gs == G_SET)
+        gaddr = put(gaddr0, slot, a0, is_sf & ~sf_link)
+        gtail = put(st.gtail, slot, a0, sf_root)
+        tcnt = put(st.tcnt, slot, tc + 1, p_fwd & is_root)
+        tcnt = put(tcnt, slot, tail_pending(cfg), p_tnull)
+        tcnt = put(tcnt, slot, fqn, sf_root)
+        link_out = wm(make_msg(OP_SET_FUTURE, gt, a0))
+        # at the old tail, that link: forward the tail's own value to the
+        # new ghost through the coalesced-forward register, so that no
+        # relax that reached the old tail before its link is lost
+        linked = is_sf & ~is_root & (gs == G_NULL)
+        fwd_val = put(fwd_val, slot,
+                      vals_s[..., 0] if QB == 1 else vals_s, linked)
+        fwd_pending = fwd_pending | _oh(slot, S, linked)
+        sf_T = sf_T + (sf_link | linked).astype(jnp.int32)
+    else:
+        gaddr = put(gaddr0, slot, a0, is_sf)
+    gstate = put(gstate, slot, G_SET, is_sf)
 
     # ---------------- combine: T, cout, registers, queue pop --------------
     T = (ins_T
-         + jnp.where(p_fwd | p_null | p_rlink | alc_room | alc_full | is_lr,
+         + jnp.where(p_fwd | asks | p_rlink | alc_room | alc_full | is_lr,
                      1, 0)
          + app_T + sf_T + rf_T)
     cout = jnp.where(p_room[..., None], ins_out,
             jnp.where(p_fwd[..., None], fwd_out,
-             jnp.where(p_null[..., None], null_out,
+             jnp.where(asks[..., None], null_out,
               jnp.where(p_rlink[..., None], rlink_out,
                jnp.where(is_lr[..., None], lr_out,
                 jnp.where(alc_room[..., None], alc_ok_out,
                  jnp.where(alc_full[..., None], alc_fwd_out, st.cout)))))))
+    if ti:
+        cout = jnp.where(sf_link[..., None], link_out, cout)
 
     # pop (feasible) or rotate-to-tail (infeasible): head always advances
     move = pop | rotate
@@ -532,6 +589,7 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
         aq=aq, aq_n=aq_n2, aq_head=aq_h2, arot=arot,
         cmsg=cmsg, cvalid=cvalid, cphase=cphase, cT=cT, cemit=cemit,
         cout=cout, cdrain=cdrain,
+        **(dict(gtail=gtail, tcnt=tcnt) if ti else {}),
         stat_exec=st.stat_exec + jnp.sum(done0.astype(jnp.int32)),
         stat_allocs=st.stat_allocs + jnp.sum(alc_room.astype(jnp.int32)),
         stat_stall=st.stat_stall + jnp.sum(rotate.astype(jnp.int32)))
@@ -543,6 +601,13 @@ def phase0_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
         dq = jnp.sum(changed_q.astype(jnp.int32), axis=(0, 1))
         st = st._replace(qchg=st.qchg + dq,
                          qlast=jnp.where(dq > 0, st.cycle, st.qlast))
+    if cfg.rhizome_cap > 1:
+        # the protocol's own work, apart from phase0's other actions: link
+        # requests popped here (each emits one OP_LINK_RHIZOME) and the
+        # OP_RHIZOME_FWD actions executed
+        st = st._replace(stat_rhz=st.stat_rhz
+                         .at[RZ_LINK].add(jnp.sum(p_rlink.astype(jnp.int32)))
+                         .at[RZ_FWD].add(jnp.sum(is_rf.astype(jnp.int32))))
     if cfg.faults is not None:
         st = st._replace(flt=st.flt.at[FLT_CORRUPT].add(
             jnp.sum(bad.astype(jnp.int32))))

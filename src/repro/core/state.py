@@ -58,6 +58,21 @@ TM_HW_AQ = 0    # action-queue depth hi-water
 TM_HW_PK = 1    # park-ring depth hi-water
 N_TM_HIW = 2
 
+# Rhizome-protocol counters (DESIGN §4.5), ``stat_rhz [N_RZ]`` when
+# rhizome_cap > 1, summed over an increment like the stat_* scalars.
+RZ_LINK = 0     # OP_LINK_RHIZOME emitted: an insert reached a secondary
+                # root that had neither been activated nor asked to be
+RZ_FWD = 1      # OP_RHIZOME_FWD actions executed (sibling broadcasts
+                # and link acks)
+N_RZ = 2
+
+
+def tail_pending(cfg) -> int:
+    """``tcnt`` of a root whose request for a new chain tail is out
+    (``cfg.tail_insert``): past ``edge_cap``, so no insert goes to the old
+    tail meanwhile."""
+    return cfg.edge_cap + 1
+
 
 class MachineState(NamedTuple):
     # --- RPVO slot storage [H, W, S, ...] ---
@@ -138,6 +153,18 @@ class MachineState(NamedTuple):
     #     [1] dummies (never touched) when qbatch == 1 ---
     qchg: jax.Array        # [Q] i32 (or [1] dummy)
     qlast: jax.Array       # [Q] i32 (or [1] dummy)
+    # --- rhizome-protocol counters (RZ_* indices, DESIGN §4.5): reset
+    #     per increment with the stat_* scalars when cfg.rhizome_cap > 1;
+    #     a [1] dummy (never touched) at rhizome_cap == 1, so the
+    #     single-root machine traces none of it ---
+    stat_rhz: jax.Array    # [N_RZ] i32 (or [1] dummy)
+    # --- chain tail of each root (cfg.tail_insert, DESIGN §4.6): the
+    #     address of the root's last ghost and the edges the root has sent
+    #     it (``tail_pending(cfg)`` while the root's request for the next
+    #     ghost is out); meaningful at primary slots only.  [1,1,1]
+    #     dummies (never touched) when tail_insert is off ---
+    gtail: jax.Array       # [H,W,S] i32 (or [1,1,1] dummy)
+    tcnt: jax.Array        # [H,W,S] i32 (or [1,1,1] dummy)
 
 
 def init_state(cfg: EngineConfig,
@@ -200,6 +227,10 @@ def init_state(cfg: EngineConfig,
         flt=z32(4 if cfg.faults is not None else 1),
         qchg=z32(QB if QB > 1 else 1),
         qlast=z32(QB if QB > 1 else 1),
+        stat_rhz=z32(N_RZ if cfg.rhizome_cap > 1 else 1),
+        gtail=jnp.full((H, W, S) if cfg.tail_insert else (1, 1, 1), -1,
+                       jnp.int32),
+        tcnt=z32(*((H, W, S) if cfg.tail_insert else (1, 1, 1))),
     )
 
 

@@ -32,7 +32,7 @@ from repro.core.state import MachineState, init_state
 # counter the increment restart resets anyway.  Shapes depend only on
 # the grid/slot geometry, never on lanes/queue_cap — asserted below.
 STORAGE_LEAVES = ("vals", "nedges", "edst", "ew", "gaddr", "gstate",
-                  "rhz_on", "rstate", "nfree", "arot")
+                  "rhz_on", "rstate", "nfree", "arot", "gtail", "tcnt")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -126,6 +126,23 @@ def test_mq_q4_device_loop_compiles_32x32(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
+def test_rmat16_rhizome_loop_fits_one_chip(one_chip):
+    """The Graph500 SCALE-16 deployment's BFS loop: ``chip_32x32_rmat16``
+    at ``rhizome_cap`` 4 holds 4 x 64 root slots and 256 ghosts, 512
+    slots a cell against the 50K shape's 244, with each root's chain tail
+    (``tail_insert``), and still fits one chip."""
+    from repro.configs import cca_paper
+    from repro.core.apps import BFS
+    spec = next(s for s in cca_paper.cca_shapes()
+                if s.name == "chip_32x32_rmat16")
+    cfg = dataclasses.replace(cca_paper.stream_config_for(spec),
+                              rhizome_cap=4, tail_insert=True)
+    assert (cfg.slots, cfg.ghost_slots, cfg.io_stream_cap) == (512, 256,
+                                                               6553)
+    mem = _compile_device_loop(cfg, BFS, one_chip).memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
 def test_sharded_chunk_compiles_on_2x2_mesh(topo):
     import jax
     import numpy as np
