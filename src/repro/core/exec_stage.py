@@ -24,10 +24,16 @@ plus the rhizome protocol of DESIGN §4.5):
   OP_LINK_RHIZOME activation request at the canonical root: mark the
                   vertex multi-root and ack with the current value
 
-Implementation note (§Perf, cca cell): every slot access is a one-hot
-``where`` over the slot axis — never a scatter/gather with index arrays —
-so GSPMD partitions each cycle over the sharded cell grid with zero
+Implementation note (§Perf, cca cell): slot writes and the small per-slot
+reads are one-hot ``where``s over the slot axis — never a scatter — so
+GSPMD partitions each cycle over the sharded cell grid with zero
 collectives beyond the routing permutes and the quiescence all-reduce.
+Staging's reads of one element per cell from the large slot leaves
+(``edst``/``ew`` at ``[slot, edge]``, ``fq`` at ``[slot, head]``) are
+gathers batched over the cell grid (:func:`take_cell`): they partition
+over the grid with no collectives either, and read a few words per cell
+where a one-hot reduce would read the whole leaf and reduce its slot axis
+across the TPU's 128 lanes every cycle.
 """
 from __future__ import annotations
 
@@ -80,6 +86,27 @@ def put(arr, slot, val, mask):
     return jnp.where(oh, val, arr)
 
 
+def take_cell(arr, slot, j):
+    """arr[II, JJ, slot, j] as one gather batched over the cell grid and
+    arr's trailing axes.  arr: [H,W,S,N,*T], slot/j: [H,W] (in range)
+    -> [H,W,*T].
+
+    Every slice is a single element, so the gather asks no layout of
+    arr: on a TPU it reads the leaf in the loop carry's own layout
+    (a slice along a lane-mapped axis would have XLA relayout the leaf).
+    """
+    nt = arr.ndim - 4
+    at = jnp.stack([slot, j], axis=-1)                           # [H,W,2]
+    at = jnp.broadcast_to(at.reshape(at.shape[:2] + (1,) * nt + (2,)),
+                          arr.shape[:2] + arr.shape[4:] + (2,))
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(2, 3), start_index_map=(2, 3),
+        operand_batching_dims=(0, 1) + tuple(range(4, arr.ndim)),
+        start_indices_batching_dims=tuple(range(2 + nt)))
+    return jax.lax.gather(arr, at, dn, (1,) * arr.ndim,
+                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
 # --------------------------------------------------------------------------
 # EXEC-A: staging — the active action emits its next message (1 per cycle)
 # --------------------------------------------------------------------------
@@ -123,9 +150,8 @@ def staging_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
     kd = k - st.cdrain             # emission index past the drains (rf)
     ne = sel(st.nedges, slot)
     ek = jnp.clip(kd, 0, E - 1)
-    ohSE = (_oh(slot, S)[..., None] & _oh(ek, E)[..., None, :])  # [H,W,S,E]
-    e_dst = jnp.sum(jnp.where(ohSE, st.edst, 0), axis=(2, 3))
-    e_w = jnp.sum(jnp.where(ohSE, st.ew, 0.0), axis=(2, 3))
+    e_dst = take_cell(st.edst, slot, ek)
+    e_w = take_cell(st.ew, slot, ek)
     app_edge_msg = qmsg(OP_APP, e_dst, app.edge_value(st.cemit, e_w))
     gs = sel(st.gstate, slot)
     ga = sel(st.gaddr, slot)
@@ -148,9 +174,7 @@ def staging_stage(cfg: EngineConfig, app: DiffusionApp, st: MachineState,
     #      then (last) the coalesced deferred app-forward, if any ----
     fqn_cur = sel(st.fq_n, slot)
     fqh_cur = sel(st.fq_head, slot)
-    fq_slot = jnp.sum(jnp.where(_expand(_oh(slot, S), st.fq), st.fq, 0),
-                      axis=2)                                # [H,W,FQ,3]
-    fq_e = rings.ring_peek(fq_slot, fqh_cur)                 # [H,W,3]
+    fq_e = take_cell(st.fq, slot, fqh_cur % cfg.futq_cap)    # [H,W,3]
     sf_is_ins = fq_e[..., 0] == OP_INSERT_EDGE
     if QB == 1:
         sf_fq_app = make_msg(OP_APP, ga, fq_e[..., 1])

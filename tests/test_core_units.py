@@ -10,6 +10,7 @@ import pytest
 from repro.core import rings
 from repro.core.alloc import choose_alloc_cell, vicinity_offsets
 from repro.core.config import EngineConfig
+from repro.core.exec_stage import take_cell
 from repro.core.msg import (TB_AQ_SELF, TB_CHAN_E, TB_CHAN_N, TB_CHAN_S,
                             TB_CHAN_W, f2i, i2f, make_msg)
 from repro.core.routing import yx_target_buffer
@@ -106,6 +107,59 @@ def test_public_docstrings(module_name):
                    if f.name == "deliver")
         assert "reserve" in doc.lower() and "aq_room" in doc, \
             "deliver must document the reserve-predicate contract"
+
+
+def _index_grid(H, W, n, seed, pin):
+    """[H,W] indices in [0, n), with the cells of ``pin`` set to its
+    values (the ends of the range, a wrapped head)."""
+    idx = np.random.default_rng(seed).integers(0, n, (H, W))
+    for (i, j), v in pin.items():
+        idx[i, j] = v
+    return jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("S", [5, 244, 512])
+def test_take_cell_reads_the_future_queue_head_as_the_one_hot_reduce(S):
+    """Staging's gather of one future-queue entry per cell equals the
+    one-hot read it replaced (the slot's queue by a masked sum over the
+    slot axis, then ``ring_peek``) for every word, at the slot counts of
+    the 50K and SCALE-16 shapes: heads past the capacity wrap, and a
+    never-written queue reads its zeros."""
+    H, W, FQ = 3, 4, 8
+    rng = np.random.default_rng(S)
+    fq = rng.integers(-2**31, 2**31, (H, W, S, FQ, 3), dtype=np.int64)
+    fq[:, :, 0] = 0                                   # empty, never written
+    fq = jnp.asarray(fq.astype(np.int32))
+    slot = _index_grid(H, W, S, S + 1, {(0, 0): 0, (2, 3): S - 1})
+    head = _index_grid(H, W, 3 * FQ, S + 2,
+                       {(0, 1): FQ - 1, (1, 0): FQ, (1, 1): 3 * FQ - 1})
+    oh = (jnp.arange(S) == slot[..., None])[..., None, None]
+    old = rings.ring_peek(jnp.sum(jnp.where(oh, fq, 0), axis=2), head)
+    new = take_cell(fq, slot, head % FQ)
+    assert new.shape == (H, W, 3)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+
+
+@pytest.mark.parametrize("S", [5, 244, 512])
+def test_take_cell_reads_the_edge_as_the_one_hot_reduce(S):
+    """Staging's gather of the emitted edge's destination and weight
+    equals the one-hot reduce over (slot, edge) it replaced.  Weights are
+    normal floats: the masked sum returns those exactly (it would turn a
+    -0.0 into 0.0, which the gather keeps)."""
+    H, W, E = 3, 4, 8
+    rng = np.random.default_rng(S)
+    edst = jnp.asarray(rng.integers(-2**31, 2**31, (H, W, S, E),
+                                    dtype=np.int64).astype(np.int32))
+    ew = jnp.asarray(rng.uniform(0.5, 100.0, (H, W, S, E)).astype(np.float32))
+    slot = _index_grid(H, W, S, S + 3, {(0, 0): 0, (2, 3): S - 1})
+    ek = _index_grid(H, W, E, S + 4, {(0, 1): 0, (1, 0): E - 1})
+    oh = ((jnp.arange(S) == slot[..., None])[..., None]
+          & (jnp.arange(E) == ek[..., None])[..., None, :])
+    for leaf in (edst, ew):
+        old = jnp.sum(jnp.where(oh, leaf, 0), axis=(2, 3)).astype(leaf.dtype)
+        new = take_cell(leaf, slot, ek)
+        assert new.shape == (H, W) and new.dtype == leaf.dtype
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
 
 
 @pytest.mark.parametrize("policy", ["vicinity", "random"])

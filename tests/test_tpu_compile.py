@@ -10,6 +10,7 @@ imports this file.  Nothing here runs, so nothing here is a timing.
 """
 import dataclasses
 import functools
+import re
 
 import pytest
 
@@ -108,7 +109,6 @@ def test_bfs_loop_keeps_vals_unpadded(bfs_loop_paper):
     stream's relayout before the loop (``io_edges [IO, L, 3]`` with its
     3-word record on the lanes, once per pass) the temporaries hold
     under 32 MB."""
-    import re
     cfg = _paper_cfg()
     io_relayout = cfg.io_cells * cfg.io_stream_cap * 128 * 4
     temp = bfs_loop_paper.memory_analysis().temp_size_in_bytes
@@ -116,6 +116,50 @@ def test_bfs_loop_keeps_vals_unpadded(bfs_loop_paper):
     carries = re.findall(r"= \((\w+\[[\d,]*\])\S* .*? while\(",
                          bfs_loop_paper.as_text())
     assert carries and set(carries) == {"f32[32,32,244]"}, carries
+
+
+def _shapes(hlo):
+    """Every instruction's result shape by name (``s32[32,32,244]``)."""
+    return dict(re.findall(r"%([\w.-]+) = (\w+\[[\d,]*\])", hlo))
+
+
+def _carry_layouts(hlo, shape):
+    """The layouts ``shape`` takes in the program's parameters and in the
+    carry of every ``while`` (minor-to-major, without tiling)."""
+    out = set()
+    pat = re.escape(shape) + r"\{([\d,]+)"
+    for line in hlo.splitlines():
+        if " parameter(" in line or re.search(r"\) while\(", line):
+            out.update(re.findall(pat, line.split(" while(")[0]))
+    return out
+
+
+def test_bfs_loop_stages_one_entry_per_cell(bfs_loop_paper):
+    """Staging reads the future queue's head and the emitted edge with
+    per-cell gathers: no reduce under ``cca.staging`` takes the whole
+    ``fq`` or ``edst`` leaf (a one-hot reduce of ``fq`` over its
+    lane-mapped slot axis cost ~95 us a machine cycle on a v5e)."""
+    hlo = bfs_loop_paper.as_text()
+    shapes = _shapes(hlo)
+    staged = re.findall(r" (reduce|gather)\(%([\w.-]+).*?op_name=\"[^\"]*"
+                        r"cca\.staging/", hlo)
+    whole = {"s32[32,32,244,8,3]", "s32[32,32,244,8]"}
+    assert not [a for op, a in staged
+                if op == "reduce" and shapes.get(a) in whole]
+    assert {shapes.get(a) for op, a in staged if op == "gather"} >= whole
+
+
+def test_bfs_loop_keeps_slot_leaf_layouts(bfs_loop_paper):
+    """``fq``, ``edst`` and ``ew`` keep one layout each, the one they are
+    passed in, through both loops: a gather that slices along the slot
+    axis (or a one-hot read it displaced) has XLA relayout such a leaf
+    with its small axis on the 128 lanes, and the loop then rewrites a
+    padded copy of it every cycle."""
+    hlo = bfs_loop_paper.as_text()
+    for shape in ("s32[32,32,244,8,3]", "s32[32,32,244,8]",
+                  "f32[32,32,244,8]"):
+        assert len(_carry_layouts(hlo, shape)) == 1, \
+            (shape, _carry_layouts(hlo, shape))
 
 
 def test_mq_q4_device_loop_compiles_32x32(one_chip):
@@ -159,7 +203,15 @@ def test_sharded_chunk_compiles_on_2x2_mesh(topo):
     compiled = jax.jit(lambda s: run_chunk_body(cfg, BFS, s),
                        in_shardings=(shards,), out_shardings=shards
                        ).lower(st).compile()
-    assert "collective-permute" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "collective-permute" in hlo
+    # the per-cell gathers partition over the cell grid: no gather of the
+    # state across chips, and each chip's slot leaves keep their layout
+    assert " all-gather" not in hlo and " all-to-all" not in hlo
+    for shape in ("s32[16,16,244,8,3]", "s32[16,16,244,8]",
+                  "f32[16,16,244,8]"):
+        assert len(_carry_layouts(hlo, shape)) == 1, \
+            (shape, _carry_layouts(hlo, shape))
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     total = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(whole))
     assert per_chip < total / 2     # the state is tiled, not replicated
